@@ -41,18 +41,6 @@ def _llama4_act_stationary_ga8(acfg):
         acfg, parallel=dataclasses.replace(acfg.parallel, grad_accum=8))
 
 
-def _pad_heads(acfg):
-    """H2: kv-SP attention replicates q over "model" -> per-layer q/k/v
-    all-gathers (~300 MB/layer/microbatch for minicpm). Padded head-TP
-    (36->48 heads, zero-padded, exact) shards the attention core instead;
-    cost: 33% extra core-attention flops (core is ~1/3 of layer flops ->
-    ~+11% t_compute). Predicted: attention collective bytes -> ~0; total
-    t_collective drops to the FSDP-gather floor (~3-5x)."""
-    return dataclasses.replace(
-        acfg, parallel=dataclasses.replace(acfg.parallel,
-                                           pad_attn_heads_to=16))
-
-
 def _qwen3_dmd_bf16_math(acfg):
     """H3: qwen3 is the MoE-DMD showcase (DMD over ALL params). The jump's
     cost is bandwidth: gram+combine read the m x params buffer in fp32
@@ -82,7 +70,6 @@ VARIANTS = {
     "baseline": lambda a: a,
     "act_stationary": _llama4_act_stationary,
     "act_stationary_ga8": _llama4_act_stationary_ga8,
-    "pad_heads": _pad_heads,
     "ga_half": _ga_half,
     "dmd_bf16_math": _qwen3_dmd_bf16_math,
 }

@@ -41,7 +41,7 @@ from repro.configs.base import (DMDConfig, DMDControllerConfig,
 from repro.core import DMDAccelerator, leafplan
 from repro.core import snapshots as snap
 from repro.core.dmd import dmd_coefficients, gram_matrix
-from repro.models.mlp_net import init_mlp, mse_loss
+from repro.models.mlp_net import MLPModel, init_mlp, mse_loss
 from repro.optim import apply_updates, make_optimizer
 
 
@@ -121,7 +121,7 @@ def _train_gated(sizes, X, Y, Xval, Yval, Xte, Yte, steps, m=14, s=55,
         parallel=ParallelConfig(grad_accum=1),
         train=TrainConfig(global_batch=int(X.shape[0]), seq_len=1),
         shapes=())
-    trainer = Trainer(_MLPModel(sizes), acfg,
+    trainer = Trainer(MLPModel(sizes), acfg,
                       val_batch={"x": Xval, "y": Yval})
     outcomes = {0: 0, 1: 0, 2: 0}
 
@@ -677,14 +677,13 @@ def sharded_gram(m=8, L=4, d0=256, d1=512, reps=10) -> List[str]:
     cfg = DMDConfig(m=m, s=40, tol=1e-4, anchor="first", warmup_steps=0,
                     cooldown_steps=0)
 
+    from repro.launch.mesh import make_mesh
+
     mesh = None
-    try:
-        ndev = len(jax.devices())
-        if ndev >= 2:
-            nd = 2 if ndev < 8 else 8
-            mesh = jax.make_mesh((nd // 2, 2), ("data", "model"))
-    except Exception:
-        mesh = None
+    ndev = len(jax.devices())
+    if ndev >= 2:
+        nd = 2 if ndev < 8 else 8
+        mesh = make_mesh((nd // 2, 2), ("data", "model"))
 
     def plans_with(route):
         c = _dc.replace(cfg, kernel_route=route)
@@ -865,21 +864,6 @@ def staggered_jump(m=14, sizes=(6, 800, 800, 800), reps=10) -> List[str]:
     return rows
 
 
-class _MLPModel:
-    """Trainer adapter for the paper's regression MLP: `init`/`loss` is the
-    whole contract Trainer needs; batches are {"x", "y"} dicts."""
-
-    def __init__(self, sizes):
-        self.sizes = sizes
-
-    def init(self, key):
-        return init_mlp(jax.random.PRNGKey(0) if key is None else key,
-                        self.sizes)
-
-    def loss(self, params, batch):
-        return mse_loss(params, batch["x"], batch["y"]), None
-
-
 def controller(steps=450, sizes=(6, 40, 100, 400), m=14, s=55,
                log_every=25) -> List[str]:
     """ISSUE 4 tentpole evidence: the loss-gated adaptive jump controller
@@ -926,7 +910,7 @@ def controller(steps=450, sizes=(6, 40, 100, 400), m=14, s=55,
             shapes=())
 
     def run(ctrl_on):
-        trainer = Trainer(_MLPModel(sizes), acfg_for(ctrl_on))
+        trainer = Trainer(MLPModel(sizes), acfg_for(ctrl_on))
         outcomes, curve = [], []
         t0 = time.time()
 
@@ -981,7 +965,7 @@ def controller(steps=450, sizes=(6, 40, 100, 400), m=14, s=55,
         is_leaf=lambda x: x is None)
 
     gated = jax.jit(make_dmd_step(acfg_for(True), acc=tr_ctl.acc,
-                                  model=_MLPModel(sizes)),
+                                  model=MLPModel(sizes)),
                     donate_argnums=(0,), static_argnames=("groups",))
     plain = jax.jit(make_dmd_step(acfg_for(False), acc=tr_fix.acc),
                     donate_argnums=(0,), static_argnames=("groups",))

@@ -87,6 +87,9 @@ def main():
     ap.add_argument("--out", default=".",
                     help="directory for the BENCH_<suite>.json files")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks.paper_benches import (arena_bench, bucket_dmd,
                                           controller, fig3_sensitivity,
                                           fig4_curves, sec3_overhead,

@@ -60,14 +60,61 @@ def alias_count(hlo: str) -> int:
     return 0
 
 
+_COMP_RE = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$")
+_INSTR_RE = re.compile(
+    r"^\s*(ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(([^)]*)")
+_TYPE_SHAPES_RE = re.compile(r"[a-z][a-z0-9]*\[[0-9,]*\]")
+
+
 def copy_ops(hlo: str, shapes: Iterable[str]) -> List[str]:
     """Copy ops whose result starts with one of ``shapes`` — a donated
     buffer that silently lost its donation shows up as exactly such a
     copy (the HLO sometimes carries a layout suffix, hence prefix
-    matching)."""
+    matching).
+
+    Every copy of a non-fusion computation counts. A copy inside a fusion
+    body counts when its result can leave the fusion as a buffer: it is
+    the body's ROOT or an operand of the ROOT tuple (a multi-output
+    fusion), or a fusion calling the body outputs one of ``shapes`` (a
+    dynamic-update-slice into a copied buffer, say). The copies left out
+    are layout changes on read inside a fusion whose outputs are none of
+    ``shapes`` — the CPU backend emits those when a consumer (LAPACK's
+    column-major ``eigh``) wants another layout of a donated Gram it only
+    reads."""
     shapes = tuple(shapes)
-    copies = re.findall(r"= (\S+?)(?:\{[^}]*\})? copy\(", hlo)
-    return [c for c in copies if any(c.startswith(s) for s in shapes)]
+
+    def watched(t: str) -> bool:
+        return any(t.startswith(s) for s in shapes)
+
+    comps: Dict[str, list] = {}
+    fusion_out: Dict[str, List[str]] = {}      # fused body -> output shapes
+    body = None
+    for line in hlo.splitlines():
+        m = _COMP_RE.match(line)
+        if m:
+            body = comps.setdefault(m.group(1), [])
+            continue
+        m = _INSTR_RE.match(line)
+        if m is None or body is None:
+            continue
+        root, name, typ, op, args = m.groups()
+        body.append((bool(root), name, typ, op, args))
+        c = re.search(r"calls=%?([\w.\-]+)", line) if op == "fusion" \
+            else None
+        if c:
+            fusion_out.setdefault(c.group(1), []).extend(
+                _TYPE_SHAPES_RE.findall(typ))
+    copies = []
+    for cname, instrs in comps.items():
+        fused = cname in fusion_out
+        escapes = fused and any(watched(t) for t in fusion_out[cname])
+        root_args = {a for is_root, _, _, op, args in instrs
+                     if is_root and op == "tuple"
+                     for a in re.findall(r"%?([\w.\-]+)", args)}
+        copies += [typ.split("{")[0] for is_root, name, typ, op, _ in instrs
+                   if op == "copy" and (not fused or is_root or escapes
+                                        or name in root_args)]
+    return [c for c in copies if watched(c)]
 
 
 def convert_ops(hlo: str) -> List[Tuple[str, str]]:

@@ -103,25 +103,6 @@ class AuditContext:
                 "groups": sched_mod.schedule_records(self.groups)}
 
 
-class MLPModel:
-    """Trainer-compatible wrapper for the paper's regression MLP (the
-    pollutant-mlp arch has no LanguageModel)."""
-
-    def __init__(self, sizes, act: str = "softsign"):
-        self.sizes = tuple(sizes)
-        self.act = act
-
-    def init(self, key=None):
-        import jax
-        from repro.models.mlp_net import init_mlp
-        return init_mlp(key if key is not None else jax.random.PRNGKey(0),
-                        self.sizes)
-
-    def loss(self, params, batch):
-        from repro.models.mlp_net import mse_loss
-        return mse_loss(params, batch["x"], batch["y"], self.act), None
-
-
 def _build_model_and_config(arch: str, reduced_flag: bool):
     """(model, acfg, example_batch) for one audit build."""
     import jax.numpy as jnp
@@ -131,6 +112,7 @@ def _build_model_and_config(arch: str, reduced_flag: bool):
     acfg = get_config(arch)
     if acfg.model.family == "mlp":
         from repro.configs.pollutant_mlp import PAPER_SIZES
+        from repro.models.mlp_net import MLPModel
         sizes = REDUCED_MLP_SIZES if reduced_flag else PAPER_SIZES
         batch_rows = 8
         model = MLPModel(sizes, acfg.model.act)
@@ -302,6 +284,7 @@ def build_context(arch: str, *, reduced: bool = False,
     from repro.audit import mutations as mut_mod
     from repro.configs.base import DMDControllerConfig
     from repro.distributed.sharding import mesh_context
+    from repro.launch.mesh import make_mesh
     from repro.train.step import audit_step_fns
 
     mutation = mut_mod.get(mutate) if mutate else None
@@ -316,7 +299,7 @@ def build_context(arch: str, *, reduced: bool = False,
     if mesh_shape:
         axis_names = {1: ("model",), 2: ("data", "model"),
                       3: ("pod", "data", "model")}[len(mesh_shape)]
-        mesh = jax.make_mesh(tuple(mesh_shape), axis_names)
+        mesh = make_mesh(tuple(mesh_shape), axis_names)
         cm = mesh_context(mesh)
 
     with cm:

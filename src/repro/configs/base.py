@@ -334,7 +334,6 @@ class ParallelConfig:
     zero1_over_pod: bool = False     # shard optimizer state over pod axis
     grad_compression: str = "none"   # none | int8 (cross-pod quantized all-reduce)
     scan_layers: bool = True         # lax.scan over layer stacks
-    pad_attn_heads_to: int = 0       # padded head-TP for indivisible heads
     # serving
     kv_seq_shard_threshold: int = 16 # shard KV by kv-head if n_kv >= this else by seq
 

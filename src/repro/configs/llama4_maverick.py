@@ -25,7 +25,6 @@ def get_config() -> ArchConfig:
         optimizer=OptimizerConfig(name="adafactor", lr=2e-4, b2=0.99,
                                   grad_clip=1.0, schedule="cosine",
                                   warmup_steps=500, total_steps=20000),
-        parallel=ParallelConfig(grad_accum=8, remat="block",      # §Perf it.2
-                                pad_attn_heads_to=16),
+        parallel=ParallelConfig(grad_accum=8, remat="block"),     # §Perf it.2
         shapes=("train_4k", "prefill_32k", "decode_32k"),
         skip_notes="long_500k skipped: pure full attention (quadratic).")

@@ -17,7 +17,6 @@ def get_config() -> ArchConfig:
                                   weight_decay=0.1, grad_clip=1.0,
                                   schedule="wsd", warmup_steps=200,
                                   total_steps=10000, decay_fraction=0.1),
-        parallel=ParallelConfig(grad_accum=8, remat="block",
-                                pad_attn_heads_to=16),
+        parallel=ParallelConfig(grad_accum=8, remat="block"),
         shapes=("train_4k", "prefill_32k", "decode_32k"),
         skip_notes="long_500k skipped: pure full attention (quadratic).")

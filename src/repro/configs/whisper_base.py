@@ -20,8 +20,7 @@ def get_config() -> ArchConfig:
         optimizer=OptimizerConfig(name="adamw", lr=1e-3, grad_clip=1.0,
                                   schedule="cosine", warmup_steps=100,
                                   total_steps=10000),
-        parallel=ParallelConfig(grad_accum=1, remat="none",
-                                pad_attn_heads_to=16),
+        parallel=ParallelConfig(grad_accum=1, remat="none"),
         shapes=("train_4k", "prefill_32k", "decode_32k"),
         skip_notes="long_500k skipped: enc-dec with full attention; 8 heads "
                    "< tp=16 -> kv-SP attention layout.")

@@ -437,7 +437,7 @@ class DMDAccelerator:
                             b.scope_n_sys(self.scope),
                             anchor_first=self.cfg.anchor == "first",
                             anchor_mean=self.cfg.anchor == "mean",
-                            block_n=b.block_n, mesh=b.mesh,
+                            block_n=b.block_n, m=b.m, mesh=b.mesh,
                             lane_axes=b.lane_axes, sys_axes=b.sys_axes)
             # diagnostic table, not a step fn: the sync is the point
             g = np.asarray(jax.device_get(g), np.float64)  # lint: allow-host-sync

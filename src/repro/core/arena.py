@@ -422,10 +422,12 @@ def split_state(x) -> Tuple[Dict[str, jnp.ndarray], PyTree]:
 
 def init_arena_buffers(table: Dict[str, ArenaBucket], cfg,
                        abstract: bool = False) -> Dict[str, Any]:
+    from repro.kernels.arena import snapshot_rows
+
     dtype = jnp.dtype(cfg.snapshot_dtype)
     out = {}
     for key, b in table.items():
-        shape = (b.n_blocks, b.m, b.block_n)
+        shape = (b.n_blocks, snapshot_rows(b.m, dtype), b.block_n)
         out[key] = (jax.ShapeDtypeStruct(shape, dtype) if abstract
                     else jnp.zeros(shape, dtype))
     return out
@@ -518,45 +520,62 @@ def _unpack_row(bucket: ArenaBucket, row: jnp.ndarray, lead: int = 0
 # Parameter residency (dmd.arena_native): params/moments live in the bucket
 # ---------------------------------------------------------------------------
 
-def tree_resident(table: Dict[str, ArenaBucket], tree: PyTree) -> PyTree:
+def pack_rows(table: Dict[str, ArenaBucket], tree: PyTree
+              ) -> Dict[str, jnp.ndarray]:
+    """{bucket: (N,) flat row} of a params-shaped ``tree``'s packed leaves;
+    each row keeps the tree's OWN leaf dtype (param dtype for params, fp32
+    for optimizer moments)."""
+    by_path = _params_by_path(tree)
+    return {key: pack_row(table[key], by_path,
+                          by_path[table[key].segments[0].path].dtype)
+            for key in sorted(table)}
+
+
+def unpack_rows(table: Dict[str, ArenaBucket],
+                arenas: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
+    """Inverse of ``pack_rows``: {leaf path: per-leaf array} (uncast: the
+    row dtype is the leaf dtype)."""
+    by_path: Dict[str, jnp.ndarray] = {}
+    for key, row in arenas.items():
+        b = table[key]
+        by_path.update(zip((seg.path for seg in b.segments),
+                           _unpack_row(b, row)))
+    return by_path
+
+
+def tree_resident(table: Dict[str, ArenaBucket], tree: PyTree,
+                  rows: Optional[Dict[str, jnp.ndarray]] = None) -> PyTree:
     """Move every packed leaf of a params-shaped ``tree`` into its bucket's
-    contiguous ``(N,)`` flat buffer (the resident layout). The buffer
-    keeps each field's OWN leaf dtype (param dtype for params, fp32 for
-    optimizer moments); packed positions of the ``leaf`` subtree become
-    None. Inverse: ``tree_leafwise``. Off the hot path — called once at
-    ``Trainer.fit`` entry."""
+    contiguous ``(N,)`` flat buffer (the resident layout); packed
+    positions of the ``leaf`` subtree become None. ``rows`` are the
+    tree's ``pack_rows``, when the caller built them already. Inverse:
+    ``tree_leafwise``. Off the hot path — ``Trainer.fit`` entry only."""
     from repro.distributed.sharding import normalize_path
 
-    by_path = _params_by_path(tree)
-    arenas: Dict[str, jnp.ndarray] = {}
-    for key in sorted(table):
-        b = table[key]
-        dtype = by_path[b.segments[0].path].dtype
-        arenas[key] = pack_row(b, by_path, dtype)
     packed = arena_paths(table)
 
     def strip(kp, leaf):
         path = normalize_path(jax.tree_util.keystr(kp))
         return None if path in packed else leaf
 
-    return make_state(arenas,
+    return make_state(rows if rows is not None else pack_rows(table, tree),
                       jax.tree_util.tree_map_with_path(strip, tree))
 
 
-def tree_leafwise(table: Dict[str, ArenaBucket], wrapper: PyTree) -> PyTree:
+def tree_leafwise(table: Dict[str, ArenaBucket], wrapper: PyTree,
+                  by_path: Optional[Dict[str, jnp.ndarray]] = None
+                  ) -> PyTree:
     """Resident wrapper -> per-leaf pytree. ALSO the in-trace zero-copy
     view expansion for the model's forward: each leaf is a static
     slice + reshape of the contiguous resident row (no data movement, no
     scatter — XLA keeps them as views), so grads of loss∘views transpose
-    to pure pad-extended slices of the flat gradient."""
+    to pure pad-extended slices of the flat gradient. ``by_path`` is the
+    wrapper's ``unpack_rows``, when the caller built it already."""
     from repro.distributed.sharding import normalize_path
 
     arenas, leaf = split_state(wrapper)
-    by_path: Dict[str, jnp.ndarray] = {}
-    for key, row in arenas.items():
-        b = table[key]
-        for seg, x in zip(b.segments, _unpack_row(b, row)):
-            by_path[seg.path] = x          # uncast: row dtype == leaf dtype
+    if by_path is None:
+        by_path = unpack_rows(table, arenas)
 
     def fill(kp, x):
         return by_path.get(normalize_path(jax.tree_util.keystr(kp)), x)
@@ -639,7 +658,7 @@ def update_grams(agrams: Dict[str, jnp.ndarray],
         row = ka.gram_row(buf, q, b.scope_block_sys(scope),
                           b.scope_n_sys(scope),
                           anchor_first=cfg.anchor == "first",
-                          block_n=b.block_n, mesh=b.mesh,
+                          block_n=b.block_n, m=b.m, mesh=b.mesh,
                           lane_axes=b.lane_axes, sys_axes=b.sys_axes)
         out[key] = dmd_math.set_gram_row(g, row, sv)
     return out
@@ -701,7 +720,7 @@ def jump(cfg, table: Dict[str, ArenaBucket], params: PyTree,
                             b.scope_n_sys(scope),
                             anchor_first=cfg.anchor == "first",
                             anchor_mean=cfg.anchor == "mean",
-                            block_n=b.block_n, mesh=b.mesh,
+                            block_n=b.block_n, m=b.m, mesh=b.mesh,
                             lane_axes=b.lane_axes, sys_axes=b.sys_axes)
             grams.append(g)
         gcat = grams[0] if len(grams) == 1 else jnp.concatenate(grams)
@@ -740,7 +759,7 @@ def jump(cfg, table: Dict[str, ArenaBucket], params: PyTree,
             # BUFFER poisons the combine even under c = e_last (0*inf=NaN);
             # never leave params less finite than the last snapshot.
             flat = jnp.where(jnp.isfinite(flat), flat,
-                             buf[:, -1, :].reshape(-1).astype(flat.dtype))
+                             buf[:, b.m - 1, :].reshape(-1).astype(flat.dtype))
             if resident:
                 updates[b.key] = flat.astype(
                     jnp.dtype(b.segments[0].param_dtype))
@@ -767,7 +786,7 @@ def buffers_leafwise(table: Dict[str, ArenaBucket],
     out = {}
     for key, buf in arenas.items():
         b = table[key]
-        slab = jnp.transpose(buf, (1, 0, 2)).reshape(b.m, b.n_lanes)
+        slab = jnp.transpose(buf[:, :b.m], (1, 0, 2)).reshape(b.m, b.n_lanes)
         for seg, arr in zip(b.segments, _unpack_row(b, slab, lead=1)):
             out[seg.path] = arr
     return out
@@ -806,7 +825,7 @@ def grams_leafwise(table: Dict[str, ArenaBucket],
             g = ka.gram(arenas[key], b.block_sys(), b.n_sys,
                         anchor_first=cfg.anchor == "first",
                         anchor_mean=cfg.anchor == "mean",
-                        block_n=b.block_n, mesh=b.mesh,
+                        block_n=b.block_n, m=b.m, mesh=b.mesh,
                         lane_axes=b.lane_axes, sys_axes=b.sys_axes)
         for seg in b.segments:
             sub = jax.lax.slice_in_dim(
@@ -824,18 +843,22 @@ def buffers_from_leafwise(table: Dict[str, ArenaBucket],
     block-major arenas (checkpoint restore path; pad lanes re-zeroed).
     The shard-local pack concatenates to snapshot-major (m, N_local) then
     re-slabs to (nb_local, m, bn) — a transpose + divisible reshape, off
-    the hot path."""
+    the hot path — and zero-pads to the stored snapshot_rows."""
+    from repro.kernels.arena import snapshot_rows
+
     dtype = jnp.dtype(cfg.snapshot_dtype)
     out = {}
     for key, b in table.items():
         leaves = [by_path[s.path] for s in b.segments]
+        rows = snapshot_rows(b.m, dtype)
 
-        def local(*ls, b=b):
+        def local(*ls, b=b, rows=rows):
             packed = jnp.concatenate(
                 [_pack_leaf_local(x, s, dtype, lead=1)
                  for x, s in zip(ls, b.segments)], axis=1)
-            return jnp.transpose(
+            blocked = jnp.transpose(
                 packed.reshape(b.m, -1, b.block_n), (1, 0, 2))
+            return jnp.pad(blocked, ((0, 0), (0, rows - b.m), (0, 0)))
 
         in_specs = tuple(s.snapshot_spec for s in b.segments)
         out[key] = _shard_wrap(b, local, in_specs, b.buffer_spec())(*leaves)

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import partition_specs, shard_map
+from repro.distributed.sharding import partition_specs
 
 PyTree = Any
 
@@ -47,7 +47,7 @@ def int8_psum_grads(grads: PyTree, mesh) -> PyTree:
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     spec_leaves = jax.tree_util.tree_leaves(
         specs, is_leaf=lambda x: isinstance(x, P))
-    synced = shard_map(
+    synced = jax.shard_map(
         sync, mesh=mesh,
         in_specs=tuple(spec_leaves),
         out_specs=tuple(spec_leaves))(*leaves)

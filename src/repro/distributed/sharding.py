@@ -27,48 +27,11 @@ _MESH: contextvars.ContextVar[Optional[Mesh]] = contextvars.ContextVar(
     "repro_mesh", default=None)
 
 
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_rep: bool = True):
-    """`jax.shard_map` across JAX versions (0.4.x only has the experimental
-    spelling; same semantics for the keyword form used here).
-
-    check_rep=False disables the per-primitive replication check — required
-    whenever the body contains a `pallas_call` (no replication rule exists
-    for it; the kernels/sharded.py wrappers pass it explicitly). Newer JAX
-    renamed the flag `check_vma`; both spellings are tried.
-    """
-    impl = getattr(jax, "shard_map", None)
-    if impl is None:
-        from jax.experimental.shard_map import shard_map as impl
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    for flag in ("check_rep", "check_vma"):
-        try:
-            return impl(f, **kw, **{flag: check_rep})
-        except TypeError:
-            continue
-    return impl(f, **kw)
-
-
-def set_mesh(mesh: Mesh):
-    """Ambient-mesh context manager across JAX versions.
-
-    `jax.set_mesh` only exists in newer JAX; 0.4.x spells it
-    `jax.sharding.use_mesh`, and before that the Mesh object itself is the
-    context manager. All three make `mesh` the ambient mesh for named-axis
-    sharding constraints, which is all this codebase needs.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
-
-
 @contextlib.contextmanager
 def mesh_context(mesh: Mesh):
     token = _MESH.set(mesh)
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             yield mesh
     finally:
         _MESH.reset(token)

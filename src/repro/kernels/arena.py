@@ -24,7 +24,7 @@ Block-major is the load-bearing layout choice, on every backend at once:
     full-buffer transpose or a poorly-vectorized fused multiply-reduce,
     measured ~2.5x slower for the streaming row pass on a deep MLP).
   * TPU: the Pallas tile IS the storage tile — block ``i`` of the grid maps
-    to ``x[i]`` with no re-tiling, and the (m_pad, block_n) VMEM tile keeps
+    to ``x[i]`` with no re-tiling, and the (m, block_n) VMEM tile keeps
     the lane axis on the 128-wide minor dimension.
   * The every-step resident record writes one ``(nb, 1, bn)`` slab per
     bucket (``dynamic_update_slice`` on the middle axis) — still a single
@@ -63,7 +63,7 @@ kernel-vs-oracle contract tests.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -74,8 +74,16 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels import ops
 
 
-def _m_pad(m: int) -> int:
-    return max(-(-m // 8) * 8, 8)
+def snapshot_rows(m: int, dtype) -> int:
+    """Stored snapshot rows of a block-major buffer: ``m`` rounded up to
+    the TPU sublane tile of ``dtype`` (8 rows of 4 bytes, 16 of 2). A
+    (nb, m, bn) buffer whose m is off the tile is laid out m-major on the
+    TPU, and every Pallas call (which wants the row-major tile) would copy
+    the whole buffer first; at the tile it is read in place. The tile
+    padding costs no HBM — the physical layout pads m to the tile anyway.
+    Rows >= m stay zero and are never written."""
+    tile = 32 // jnp.dtype(dtype).itemsize
+    return -(-m // tile) * tile
 
 
 # ---------------------------------------------------------------------------
@@ -84,8 +92,10 @@ def _m_pad(m: int) -> int:
 # ---------------------------------------------------------------------------
 
 def gram_row_ref(x: jnp.ndarray, q: jnp.ndarray, block_sys, n_sys: int, *,
-                 anchor_first: bool = False, block_n: int) -> jnp.ndarray:
-    """(nb, m, bn), (nb, bn) -> (n_sys, m) of <d_q, d_j> per system.
+                 anchor_first: bool = False, block_n: int,
+                 m: Optional[int] = None) -> jnp.ndarray:
+    """(nb, rows, bn), (nb, bn) -> (n_sys, m) of <d_q, d_j> per system
+    (``m`` real rows of the ``rows`` stored; default all).
 
     Always contracts in fp32, exactly like the per-leaf kernel oracles
     (kernels/ref.py) and the per-tile upcast in the Pallas bodies — the
@@ -116,14 +126,14 @@ def gram_row_ref(x: jnp.ndarray, q: jnp.ndarray, block_sys, n_sys: int, *,
         preferred_element_type=jnp.float32)                   # (nb, m)
     if anchor_first:
         part = part - part[:, 0:1]
-    return jax.ops.segment_sum(part, jnp.asarray(block_sys),
+    return jax.ops.segment_sum(part[:, :m], jnp.asarray(block_sys),
                                num_segments=n_sys, indices_are_sorted=True)
 
 
 def gram_ref(x: jnp.ndarray, block_sys, n_sys: int, *,
              anchor_first: bool = False, anchor_mean: bool = False,
-             block_n: int) -> jnp.ndarray:
-    """(nb, m, bn) -> (n_sys, m, m) full Grams, one per system (fp32
+             block_n: int, m: Optional[int] = None) -> jnp.ndarray:
+    """(nb, rows, bn) -> (n_sys, m, m) full Grams, one per system (fp32
     contraction regardless of storage dtype — see gram_row_ref).
 
     ``anchor_mean`` subtracts the per-lane snapshot mean before the
@@ -137,15 +147,17 @@ def gram_ref(x: jnp.ndarray, block_sys, n_sys: int, *,
     if anchor_first and anchor_mean:
         raise ValueError("anchor_first and anchor_mean are exclusive")
     del block_n
-    xf = x.astype(jnp.float32)          # (nb, m, bn)
+    m = x.shape[1] if m is None else m
+    xf = x.astype(jnp.float32)          # (nb, rows, bn)
     if anchor_first:
         xf = xf - xf[:, 0:1, :]
     if anchor_mean:
-        xf = xf - jnp.mean(xf, axis=1, keepdims=True)
+        # rows >= m are zero: sum/m is the mean of the real rows
+        xf = xf - jnp.sum(xf, axis=1, keepdims=True) / m
     part = jax.lax.dot_general(
         xf, xf, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)                   # (nb, m, m)
-    return jax.ops.segment_sum(part, jnp.asarray(block_sys),
+        preferred_element_type=jnp.float32)                   # (nb, rows, rows)
+    return jax.ops.segment_sum(part[:, :m, :m], jnp.asarray(block_sys),
                                num_segments=n_sys, indices_are_sorted=True)
 
 
@@ -183,14 +195,20 @@ def _row_kernel(seg_ref, x_ref, q_ref, out_ref, *, anchor_first: bool):
     i = pl.program_id(0)
     first = jnp.logical_or(i == 0,
                            seg_ref[i] != seg_ref[jnp.maximum(i - 1, 0)])
-    x = x_ref[0].astype(jnp.float32)              # (m_pad, block_n)
-    q = q_ref[...].astype(jnp.float32)            # (1, block_n)
+    x = x_ref[0].astype(jnp.float32)              # (rows, block_n)
+    q = q_ref[...].astype(jnp.float32)            # (tile, block_n)
     if anchor_first:
         q = q - x[0:1, :]
         x = x - x[0:1, :]
-    part = jax.lax.dot_general(
+    full = jax.lax.dot_general(
         q, x, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)        # (1, m_pad)
+        preferred_element_type=jnp.float32)        # (tile, rows)
+    # block i's query row is row i % tile of the (tile, block_n) q block
+    # (rows of other blocks — or past the array's end — are masked out)
+    pick = jax.lax.broadcasted_iota(jnp.int32, full.shape, 0) == \
+        i % q.shape[0]
+    part = jnp.sum(jnp.where(pick, full, 0.0), axis=0,
+                   keepdims=True)[None]            # (1, 1, rows)
 
     @pl.when(first)
     def _init():
@@ -201,47 +219,62 @@ def _row_kernel(seg_ref, x_ref, q_ref, out_ref, *, anchor_first: bool):
         out_ref[...] += part
 
 
+# Block shapes: the TPU tiling rule wants each block's last two dims to be
+# (8, 128)-divisible OR equal to the array's. The snapshot block is
+# (1, rows, block_n) — rows equals the array's own row count, and is the
+# sublane tile multiple (snapshot_rows), so the buffer is read in place.
+# Per-system vectors (the coefficients, the Gram-row outputs) are carried
+# as 3-D (n_sys, 1, width) arrays whose trailing (1, width) block equals
+# the array's own trailing dims. The (nb, bn) query row is read as its
+# natural (tile, bn) sublane tiles — a (1, bn) block is refused, and a 3-D
+# (nb, 1, bn) view would cost a relayout copy of q per call — and each
+# block picks its own row out of the tile.
+
 @functools.partial(jax.jit, static_argnames=("n_sys", "anchor_first",
-                                             "block_n", "interpret"))
+                                             "block_n", "m", "interpret"))
 def gram_row_pallas(x: jnp.ndarray, q: jnp.ndarray, block_sys, n_sys: int, *,
                     anchor_first: bool = False, block_n: int,
+                    m: Optional[int] = None,
                     interpret: bool = True) -> jnp.ndarray:
-    nb, m, _ = x.shape
-    mp = _m_pad(m)
-    if mp != m:
-        x = jnp.pad(x, ((0, 0), (0, mp - m), (0, 0)))
-    grid = (nb,)
+    """(nb, rows, bn), (nb, bn) -> (n_sys, m): the kernel walks every
+    stored row (``rows`` >= m, see snapshot_rows); ``m`` (default: all
+    rows) keeps the real ones."""
+    nb, rows, _ = x.shape
+    m = rows if m is None else m
+    tile = 32 // jnp.dtype(q.dtype).itemsize       # q's sublane tile rows
     out = pl.pallas_call(
         functools.partial(_row_kernel, anchor_first=anchor_first),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[pl.BlockSpec((1, mp, block_n), lambda i, s: (i, 0, 0)),
-                      pl.BlockSpec((1, block_n), lambda i, s: (i, 0))],
-            out_specs=pl.BlockSpec((1, mp), lambda i, s: (s[i], 0)),
+            grid=(nb,),
+            in_specs=[pl.BlockSpec((1, rows, block_n),
+                                   lambda i, s: (i, 0, 0)),
+                      pl.BlockSpec((tile, block_n),
+                                   lambda i, s: (i // tile, 0))],
+            out_specs=pl.BlockSpec((1, 1, rows), lambda i, s: (s[i], 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((n_sys, mp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_sys, 1, rows), jnp.float32),
         interpret=interpret,
     )(jnp.asarray(block_sys, jnp.int32), x, q)
-    return out[:, :m]
+    return out[:, 0, :m]
 
 
 def _gram_kernel(seg_ref, x_ref, out_ref, *, anchor_first: bool,
-                 m_real: int):
+                 mean_rows: int):
     i = pl.program_id(0)
     first = jnp.logical_or(i == 0,
                            seg_ref[i] != seg_ref[jnp.maximum(i - 1, 0)])
-    x = x_ref[0].astype(jnp.float32)              # (m_pad, block_n)
+    x = x_ref[0].astype(jnp.float32)              # (rows, block_n)
     if anchor_first:
         x = x - x[0:1, :]
-    if m_real > 0:
-        # mean anchoring: pad rows are zero so sum/m_real is the exact
-        # per-lane mean; subtracting it contaminates only the pad rows,
-        # whose Gram entries land at indices >= m and are sliced away.
-        x = x - jnp.sum(x, axis=0, keepdims=True) / m_real
+    if mean_rows:
+        # mean anchoring: rows >= m are zero so sum/m is the exact
+        # per-lane mean; subtracting it contaminates only those rows, whose
+        # Gram entries land at indices >= m and are sliced away.
+        x = x - jnp.sum(x, axis=0, keepdims=True) / mean_rows
     part = jax.lax.dot_general(
         x, x, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)[None]  # (1, m_pad, m_pad)
+        preferred_element_type=jnp.float32)[None]  # (1, rows, rows)
 
     @pl.when(first)
     def _init():
@@ -253,29 +286,29 @@ def _gram_kernel(seg_ref, x_ref, out_ref, *, anchor_first: bool,
 
 
 @functools.partial(jax.jit, static_argnames=("n_sys", "anchor_first",
-                                             "anchor_mean", "block_n",
+                                             "anchor_mean", "block_n", "m",
                                              "interpret"))
 def gram_pallas(x: jnp.ndarray, block_sys, n_sys: int, *,
                 anchor_first: bool = False, anchor_mean: bool = False,
-                block_n: int, interpret: bool = True) -> jnp.ndarray:
+                block_n: int, m: Optional[int] = None,
+                interpret: bool = True) -> jnp.ndarray:
+    """(nb, rows, bn) -> (n_sys, m, m); ``m`` as in gram_row_pallas."""
     if anchor_first and anchor_mean:
         raise ValueError("anchor_first and anchor_mean are exclusive")
-    nb, m, _ = x.shape
-    mp = _m_pad(m)
-    if mp != m:
-        x = jnp.pad(x, ((0, 0), (0, mp - m), (0, 0)))
-    grid = (nb,)
+    nb, rows, _ = x.shape
+    m = rows if m is None else m
     out = pl.pallas_call(
         functools.partial(_gram_kernel, anchor_first=anchor_first,
-                          m_real=m if anchor_mean else 0),
+                          mean_rows=m if anchor_mean else 0),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[pl.BlockSpec((1, mp, block_n),
+            grid=(nb,),
+            in_specs=[pl.BlockSpec((1, rows, block_n),
                                    lambda i, s: (i, 0, 0))],
-            out_specs=pl.BlockSpec((1, mp, mp), lambda i, s: (s[i], 0, 0)),
+            out_specs=pl.BlockSpec((1, rows, rows),
+                                   lambda i, s: (s[i], 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((n_sys, mp, mp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_sys, rows, rows), jnp.float32),
         interpret=interpret,
     )(jnp.asarray(block_sys, jnp.int32), x)
     return out[:, :m, :m]
@@ -283,8 +316,8 @@ def gram_pallas(x: jnp.ndarray, block_sys, n_sys: int, *,
 
 def _combine_kernel(seg_ref, c_ref, x_ref, out_ref):
     del seg_ref                                   # consumed by the index maps
-    x = x_ref[0].astype(jnp.float32)              # (m_pad, block_n)
-    c = c_ref[...].astype(jnp.float32)            # (1, m_pad)
+    x = x_ref[0].astype(jnp.float32)              # (rows, block_n)
+    c = c_ref[0]                                  # (1, rows)
     out_ref[...] = jax.lax.dot_general(
         c, x, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)        # (1, block_n)
@@ -293,26 +326,26 @@ def _combine_kernel(seg_ref, c_ref, x_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def combine_pallas(x: jnp.ndarray, c: jnp.ndarray, block_sys, *,
                    block_n: int, interpret: bool = True) -> jnp.ndarray:
-    nb, m, _ = x.shape
-    n = nb * block_n
-    mp = _m_pad(m)
-    if mp != m:
-        x = jnp.pad(x, ((0, 0), (0, mp - m), (0, 0)))
-        c = jnp.pad(c.astype(jnp.float32), ((0, 0), (0, mp - m)))
-    grid = (nb,)
+    """(nb, rows, bn), (n_sys, m) -> (N,); rows >= m are zero-weighted."""
+    nb, rows, _ = x.shape
+    n_sys, m = c.shape
+    c = c.astype(jnp.float32)
+    if rows != m:
+        c = jnp.pad(c, ((0, 0), (0, rows - m)))   # (n_sys, rows): tiny
     out = pl.pallas_call(
         _combine_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[pl.BlockSpec((1, mp), lambda i, s: (s[i], 0)),
-                      pl.BlockSpec((1, mp, block_n),
+            grid=(nb,),
+            in_specs=[pl.BlockSpec((1, 1, rows), lambda i, s: (s[i], 0, 0)),
+                      pl.BlockSpec((1, rows, block_n),
                                    lambda i, s: (i, 0, 0))],
             out_specs=pl.BlockSpec((1, block_n), lambda i, s: (0, i)),
         ),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, nb * block_n), jnp.float32),
         interpret=interpret,
-    )(jnp.asarray(block_sys, jnp.int32), c.astype(jnp.float32), x)
+    )(jnp.asarray(block_sys, jnp.int32),
+      c.reshape(n_sys, 1, rows), x)
     return out[0]
 
 
@@ -320,43 +353,53 @@ def combine_pallas(x: jnp.ndarray, c: jnp.ndarray, block_sys, *,
 # Dispatch (kernels/ops.py contract) + shard_map wrappers for sharded buckets
 # ---------------------------------------------------------------------------
 
-def _local_gram_row(x, q, block_sys, n_sys, anchor_first, block_n,
+# Every entry of a real row's result reads only real rows, so the Gram
+# passes run over all stored rows and slice (slicing the buffer instead
+# would duplicate the record's in-place row write into the reading
+# fusion and cost a whole-buffer copy). combine contracts over the rows,
+# so its ref route contracts only the m real ones: the oracle's reduction
+# order then does not depend on the stored padding.
+
+def _local_gram_row(x, q, block_sys, n_sys, anchor_first, block_n, m,
                     interpret):
     if ops._route(interpret) == "ref":
         return gram_row_ref(x, q, block_sys, n_sys,
-                            anchor_first=anchor_first, block_n=block_n)
+                            anchor_first=anchor_first, block_n=block_n, m=m)
     return gram_row_pallas(x, q, block_sys, n_sys, anchor_first=anchor_first,
-                           block_n=block_n, interpret=ops._interp(interpret))
+                           block_n=block_n, m=m,
+                           interpret=ops._interp(interpret))
 
 
-def _local_gram(x, block_sys, n_sys, anchor_first, anchor_mean, block_n,
+def _local_gram(x, block_sys, n_sys, anchor_first, anchor_mean, block_n, m,
                 interpret):
     if ops._route(interpret) == "ref":
-        return gram_ref(x, block_sys, n_sys, anchor_first=anchor_first,
-                        anchor_mean=anchor_mean, block_n=block_n)
+        return gram_ref(x, block_sys, n_sys,
+                        anchor_first=anchor_first, anchor_mean=anchor_mean,
+                        block_n=block_n, m=m)
     return gram_pallas(x, block_sys, n_sys, anchor_first=anchor_first,
-                       anchor_mean=anchor_mean, block_n=block_n,
+                       anchor_mean=anchor_mean, block_n=block_n, m=m,
                        interpret=ops._interp(interpret))
 
 
 def _local_combine(x, c, block_sys, block_n, interpret):
     if ops._route(interpret) == "ref":
-        return combine_ref(x, c, block_sys, block_n=block_n)
+        return combine_ref(x[:, :c.shape[-1]], c, block_sys, block_n=block_n)
     return combine_pallas(x, c, block_sys, block_n=block_n,
                           interpret=ops._interp(interpret))
 
 
 def shard_wrap(mesh, lane_axes: Tuple[str, ...], fn, in_specs, out_specs):
-    """sharded.py's shard_map pattern: no mesh / no sharded lanes -> the
-    local computation IS the global one; otherwise run per shard. The ONE
-    home of the arena shard_map contract — core/arena.py's pack/unpack
-    wraps through this too, so the kernel path and the data-layout path
-    can never diverge."""
-    if mesh is None or not lane_axes:
+    """sharded.py's shard_map pattern: no mesh (or a one-device mesh) ->
+    the local computation IS the global one; otherwise run per shard — a
+    replicated bucket (no ``lane_axes``) runs whole on every device, since
+    the partitioner cannot split a Pallas call itself. The ONE home of the
+    arena shard_map contract — core/arena.py's pack/unpack wraps through
+    this too, so the kernel path and the data-layout path can never
+    diverge."""
+    if mesh is None or mesh.size == 1:
         return fn
-    from repro.distributed.sharding import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def lane_spec(lane_axes: Tuple[str, ...]) -> P:
@@ -384,11 +427,13 @@ def buf_spec(axes: Tuple[str, ...]) -> P:
 
 def gram_row(buf: jnp.ndarray, q: jnp.ndarray, block_sys, n_sys: int, *,
              anchor_first: bool = False, block_n: int,
+             m: Optional[int] = None,
              mesh=None, lane_axes: Tuple[str, ...] = (),
              sys_axes: Tuple[str, ...] = (),
              interpret=None) -> jnp.ndarray:
     """One streaming Gram row per system, ONE launch for the whole arena.
-    ``buf`` is block-major (nb, m, bn) and ``q`` its blocked query row
+    ``buf`` is block-major (nb, rows, bn) holding ``m`` real snapshot rows
+    (default: all rows; see snapshot_rows) and ``q`` its blocked query row
     (nb, bn). ``block_sys`` is the (shard-local) block->system table and
     ``n_sys`` the shard-LOCAL system count. Lane-sharded buckets
     (``lane_axes``) run per shard + one O(n_sys·m) psum; system-sharded
@@ -396,10 +441,11 @@ def gram_row(buf: jnp.ndarray, q: jnp.ndarray, block_sys, n_sys: int, *,
     sharded) need NO collective: each shard owns whole systems, and the
     output stays sharded over its system axis."""
     axes = sys_axes + lane_axes
+    m = buf.shape[1] if m is None else m
 
     def local(x, qq):
         r = _local_gram_row(x, qq, block_sys, n_sys, anchor_first, block_n,
-                            interpret)
+                            m, interpret)
         return jax.lax.psum(r, lane_axes) if lane_axes else r
 
     return shard_wrap(mesh, axes, local,
@@ -409,17 +455,18 @@ def gram_row(buf: jnp.ndarray, q: jnp.ndarray, block_sys, n_sys: int, *,
 
 def gram(buf: jnp.ndarray, block_sys, n_sys: int, *,
          anchor_first: bool = False, anchor_mean: bool = False,
-         block_n: int, mesh=None, lane_axes: Tuple[str, ...] = (),
-         sys_axes: Tuple[str, ...] = (),
+         block_n: int, m: Optional[int] = None, mesh=None,
+         lane_axes: Tuple[str, ...] = (), sys_axes: Tuple[str, ...] = (),
          interpret=None) -> jnp.ndarray:
     """Full (n_sys, m, m) Gram recompute, ONE launch + one O(n_sys·m²) psum
     over the lane axes (the non-streaming A/B path and the
     restore-staleness rebuild). System-sharded outputs stay sharded."""
     axes = sys_axes + lane_axes
+    m = buf.shape[1] if m is None else m
 
     def local(x):
         g = _local_gram(x, block_sys, n_sys, anchor_first, anchor_mean,
-                        block_n, interpret)
+                        block_n, m, interpret)
         return jax.lax.psum(g, lane_axes) if lane_axes else g
 
     return shard_wrap(mesh, axes, local,
@@ -431,7 +478,8 @@ def combine(buf: jnp.ndarray, c: jnp.ndarray, block_sys, *,
             block_n: int, mesh=None,
             lane_axes: Tuple[str, ...] = (),
             sys_axes: Tuple[str, ...] = (), interpret=None) -> jnp.ndarray:
-    """(N,) fp32 jump blend, ONE launch, zero collectives: c is replicated
+    """(N,) fp32 jump blend, ONE launch, zero collectives: ``c`` is
+    (n_sys, m) over the buffer's m real rows, replicated
     over the lane axes (sharded over the system axes, matching the Gram
     stack) and every block contracts only its own system's replicated
     snapshot axis, so the flat output inherits the arena's lane sharding."""

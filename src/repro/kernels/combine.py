@@ -16,8 +16,8 @@ from jax.experimental import pallas as pl
 
 
 def _combine_kernel(c_ref, x_ref, out_ref):
-    x = x_ref[...].astype(jnp.float32)            # (m_pad, block_n)
-    c = c_ref[...].astype(jnp.float32)            # (1, m_pad)
+    x = x_ref[...].astype(jnp.float32)            # (m, block_n)
+    c = c_ref[...].astype(jnp.float32)            # (1, m)
     out_ref[...] = jax.lax.dot_general(
         c, x, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)        # (1, block_n)
@@ -28,20 +28,17 @@ def combine_pallas(snapshots: jnp.ndarray, c: jnp.ndarray, *,
                    block_n: int = 2048, interpret: bool = True) -> jnp.ndarray:
     """(m, n), (m,) -> (n,) fp32."""
     m, n = snapshots.shape
-    m_pad = max(-(-m // 8) * 8, 8)
     n_pad = -(-n // block_n) * block_n
     x = snapshots
-    if (m_pad, n_pad) != (m, n):
-        x = jnp.pad(x, ((0, m_pad - m), (0, n_pad - n)))
-    c2 = jnp.pad(c.astype(jnp.float32), (0, m_pad - m)).reshape(1, m_pad)
-    grid = (n_pad // block_n,)
+    if n_pad != n:
+        x = jnp.pad(x, ((0, 0), (0, n_pad - n)))
     out = pl.pallas_call(
         _combine_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, m_pad), lambda i: (0, 0)),
-                  pl.BlockSpec((m_pad, block_n), lambda i: (0, i))],
+        grid=(n_pad // block_n,),
+        in_specs=[pl.BlockSpec((1, m), lambda i: (0, 0)),
+                  pl.BlockSpec((m, block_n), lambda i: (0, i))],
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
         interpret=interpret,
-    )(c2, x)
+    )(c.astype(jnp.float32).reshape(1, m), x)
     return out[0, :n]

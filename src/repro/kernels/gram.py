@@ -7,9 +7,10 @@ scratch across the whole grid (m <= 32). The anchor subtraction (D = S -
 S[0], the fp32-conditioning fix) is fused into the same pass — row 0 of each
 tile IS the anchor slice, so anchoring costs zero extra bandwidth.
 
-Tiling: grid over n // block_n; block (m_pad, block_n) with m padded to the
-8-row sublane multiple and block_n a multiple of 128 lanes. One MXU
-contraction (m_pad x block_n) @ (block_n x m_pad) per step.
+Tiling: grid over n // block_n; block (m, block_n) — m is the array's own
+row count, which the TPU tiling rule accepts without padding — and block_n
+a multiple of 128 lanes. One MXU contraction (m x block_n) @ (block_n x m)
+per step.
 """
 from __future__ import annotations
 
@@ -21,14 +22,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _gram_kernel(x_ref, out_ref, acc_ref, *, anchor_first: bool, m: int):
+def _gram_kernel(x_ref, out_ref, acc_ref, *, anchor_first: bool):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.float32)            # (m_pad, block_n)
+    x = x_ref[...].astype(jnp.float32)            # (m, block_n)
     if anchor_first:
         x = x - x[0:1, :]
     acc_ref[...] += jax.lax.dot_general(
@@ -44,24 +45,19 @@ def _gram_kernel(x_ref, out_ref, acc_ref, *, anchor_first: bool, m: int):
                    static_argnames=("anchor_first", "block_n", "interpret"))
 def gram_pallas(snapshots: jnp.ndarray, *, anchor_first: bool = False,
                 block_n: int = 2048, interpret: bool = True) -> jnp.ndarray:
-    """(m, n) -> (m, m) fp32. Pads m to 8 and n to block_n (zero rows/cols
-    contribute zero to the Gram, so padding is exact)."""
+    """(m, n) -> (m, m) fp32. Pads n to block_n (zero lanes contribute zero
+    to the Gram, so padding is exact)."""
     m, n = snapshots.shape
-    m_pad = max(-(-m // 8) * 8, 8)
     n_pad = -(-n // block_n) * block_n
     x = snapshots
-    if (m_pad, n_pad) != (m, n):
-        x = jnp.pad(x, ((0, m_pad - m), (0, n_pad - n)))
-    grid = (n_pad // block_n,)
-    out = pl.pallas_call(
-        functools.partial(_gram_kernel, anchor_first=anchor_first, m=m),
-        grid=grid,
-        in_specs=[pl.BlockSpec((m_pad, block_n), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((m_pad, m_pad), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((m_pad, m_pad), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((m_pad, m_pad), jnp.float32)]
-        if not interpret else
-        [pltpu.VMEM((m_pad, m_pad), jnp.float32)],
+    if n_pad != n:
+        x = jnp.pad(x, ((0, 0), (0, n_pad - n)))
+    return pl.pallas_call(
+        functools.partial(_gram_kernel, anchor_first=anchor_first),
+        grid=(n_pad // block_n,),
+        in_specs=[pl.BlockSpec((m, block_n), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((m, m), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((m, m), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((m, m), jnp.float32)],
         interpret=interpret,
     )(x)
-    return out[:m, :m]

@@ -45,6 +45,12 @@ def active_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
+def pallas_compiled() -> bool:
+    """True when the automatic routing runs the DMD kernels as compiled
+    Pallas: the Pallas route on a TPU, no interpreter."""
+    return active_backend() == "pallas" and not _interp(None)
+
+
 def _route(interpret) -> str:
     """interpret=None -> backend routing; interpret=True/False -> Pallas with
     that interpreter setting (the explicit kernel-test path)."""

@@ -25,7 +25,7 @@ VMEM, so there is never an HBM-sized fp32 materialization.
 Inside shard_map the local call goes through `kernels.ops`, so backend
 dispatch still applies: compiled Pallas on TPU, dot_general refs on CPU, and
 `ops.set_backend("pallas")` + interpret for the kernel-contract tests. The
-shard_map wrapper needs `check_rep=False` (no replication rule exists for
+shard_map wrapper needs `check_vma=False` (no replication rule exists for
 `pallas_call`).
 
 With no mesh on the plan the wrappers degrade to the same local computation
@@ -89,9 +89,8 @@ def _local_combine(x, c, k, block_n, interpret):
 def _wrap(plan, fn, in_specs, out_specs):
     if plan.mesh is None:
         return fn
-    from repro.distributed.sharding import shard_map
-    return shard_map(fn, mesh=plan.mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=plan.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def gram(buf: jnp.ndarray, plan, *, anchor_first: bool = False,

@@ -60,7 +60,7 @@ def build_step(acfg, shape, mesh, scan_layers: bool = True):
     mc = acfg.model
     model = LanguageModel(mc, chunk_k=min(1024, shape.seq_len),
                           remat=acfg.parallel.remat, scan_layers=scan_layers,
-                          pad_heads_to=acfg.parallel.pad_attn_heads_to)
+                          pad_heads_to=mesh.shape["model"])
     batch, batch_specs = inputs_mod.input_specs(acfg, shape, mesh)
 
     if shape.kind == "train":

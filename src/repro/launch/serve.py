@@ -10,8 +10,9 @@ compiled program per bucket, zero steady-state recompiles — slot-based
 decode over donated KV/decode state with in-jit sampling (no host sync
 per token), and optional live weight hot-swaps mid-stream
 (``--swap-every``) to demo the version-stamped double-buffered publish
-path. On TPU slices the full config runs on the production mesh with the
-slot table sharded per ``launch/inputs.serve_state_specs``.
+path. Without --reduced the full config runs on a (data, model) mesh over
+every device JAX finds, with the slot table sharded per
+``launch/inputs.serve_state_specs``.
 
 The per-token decode loop of the seed-era launcher (an
 ``argmax(logits[:, -1])`` host round-trip between every pair of
@@ -50,7 +51,7 @@ def main():
     ap.add_argument("--swap-every", type=int, default=0,
                     help="hot-swap perturbed weights every N engine steps "
                          "(0 = frozen server)")
-    ap.add_argument("--multi-pod", action="store_true")
+    ap.add_argument("--model-parallel", type=int, default=1)
     args = ap.parse_args()
 
     import jax
@@ -62,11 +63,14 @@ def main():
 
     acfg = get_config(args.arch)
     mc = reduced(acfg.model) if args.reduced else acfg.model
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     mesh_cm = None
     if not args.reduced:
-        from repro.launch.mesh import make_production_mesh
-        mesh_cm = mesh_context(make_production_mesh(
-            multi_pod=args.multi_pod))
+        from repro.launch.mesh import make_mesh_for_devices
+        mesh_cm = mesh_context(make_mesh_for_devices(
+            len(jax.devices()), args.model_parallel))
 
     def run():
         # scan_layers=False: serving unrolls the layer stack so XLA updates

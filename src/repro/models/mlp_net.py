@@ -42,3 +42,20 @@ def mse_loss(params, x, y, activation: str = "softsign"):
 
 
 PAPER_SIZES: Tuple[int, ...] = (6, 40, 200, 1000, 2670)
+
+
+class MLPModel:
+    """Trainer adapter for the regression MLP: ``init``/``loss`` is the
+    whole contract the Trainer needs; batches are {"x", "y"} dicts."""
+
+    def __init__(self, sizes: Sequence[int] = PAPER_SIZES,
+                 act: str = "softsign"):
+        self.sizes = tuple(sizes)
+        self.act = act
+
+    def init(self, key=None):
+        return init_mlp(key if key is not None else jax.random.PRNGKey(0),
+                        self.sizes)
+
+    def loss(self, params, batch):
+        return mse_loss(params, batch["x"], batch["y"], self.act), None

@@ -129,14 +129,44 @@ class Trainer:
 
     # -- state ---------------------------------------------------------------
     def init_state(self, key=None) -> TrainState:
-        params = self.model.init(key if key is not None
-                                 else jax.random.PRNGKey(self.acfg.train.seed))
-        opt_state = self.opt.init(params)
-        bufs = self.acc.init(params) if self.acfg.dmd.enabled else None
-        grams = self.acc.init_grams(bufs)
-        ctrl = self.acc.init_controller()
-        return TrainState(params, opt_state, jnp.zeros((), jnp.int32), bufs,
-                          grams, ctrl)
+        key = (key if key is not None
+               else jax.random.PRNGKey(self.acfg.train.seed))
+
+        def fresh(key):
+            params = self.model.init(key)
+            bufs = self.acc.init(params) if self.acfg.dmd.enabled else None
+            return TrainState(params, self.opt.init(params),
+                              jnp.zeros((), jnp.int32), bufs,
+                              self.acc.init_grams(bufs),
+                              self.acc.init_controller())
+
+        if self.mesh is None:
+            return fresh(key)
+        # One program builds the state straight into its planned shardings:
+        # no leaf (the snapshot arena above all) is ever whole on a device.
+        return jax.jit(fresh, out_shardings=self._shardings(
+            jax.eval_shape(fresh, key)))(key)
+
+    def _shardings(self, state: TrainState) -> TrainState:
+        """NamedShardings of every state leaf (concrete or abstract). DMD
+        buffer/Gram specs come from the plan table, so a fresh state starts
+        out sharded exactly as the steps keep it, and a checkpoint written
+        on one topology restores onto any other."""
+        from repro.launch.inputs import shardings_of, state_specs
+        return shardings_of(
+            state_specs(state, self.mesh,
+                        plans=self.acc.plans_for(state.params),
+                        arena=self.acc.arena_for(state.params)),
+            self.mesh)
+
+    def _place(self, state: TrainState) -> TrainState:
+        """Put every state leaf on the mesh with its planned sharding (no-op
+        without a mesh)."""
+        if self.mesh is None:
+            return state
+        return jax.tree_util.tree_map(
+            lambda x, s: None if x is None else jax.device_put(x, s),
+            state, self._shardings(state), is_leaf=lambda x: x is None)
 
     # -- checkpointing --------------------------------------------------------
     def save(self, state: TrainState, step: int):
@@ -163,23 +193,13 @@ class Trainer:
                                    mesh=self.mesh)
         if state is None:
             return None
-        if self.mesh is not None:
-            # Elastic restore: re-place every restored leaf against the
-            # CURRENT mesh's shardings BEFORE any computation touches the
-            # state — a checkpoint written on one topology restores onto
-            # any other, and the arena-unpacked template can leave buffer
-            # leaves committed to the mesh while Gram leaves are
-            # single-device (shard_map outputs vs plain slices), which
-            # would poison the first jit below with mixed placements. DMD
-            # buffer/Gram specs come from the plan table.
-            from repro.launch.inputs import shardings_of, state_specs
-            sh = shardings_of(
-                state_specs(state, self.mesh,
-                            plans=self.acc.plans_for(state.params)),
-                self.mesh)
-            state = jax.tree_util.tree_map(
-                lambda x, s: None if x is None else jax.device_put(x, s),
-                state, sh, is_leaf=lambda x: x is None)
+        # Elastic restore: re-place every restored leaf against the CURRENT
+        # mesh's shardings BEFORE any computation touches the state — the
+        # arena-unpacked template can leave buffer leaves committed to the
+        # mesh while Gram leaves are single-device (shard_map outputs vs
+        # plain slices), which would poison the first jit below with mixed
+        # placements.
+        state = self._place(state)
         if self.acc.streaming and state.dmd_gram is not None:
             # Pre-streaming checkpoints restore the template's all-zero
             # Grams; rebuild those from the restored buffers so a mid-window

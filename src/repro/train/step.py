@@ -55,6 +55,7 @@ gate-overhead fix in benchmarks/paper_benches.py).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Optional, Sequence
 
 import jax
@@ -110,22 +111,26 @@ def state_resident(acc: DMDAccelerator, acfg, state):
     if not table:
         return state
     pdef = jax.tree_util.tree_structure(state.params)
-
-    def to_res(field):
-        # params-shaped moment trees pack; anything else (scalar counters,
-        # empty states) passes through untouched
-        if jax.tree_util.tree_structure(field) == pdef:
-            return arena_mod.tree_resident(table, field)
-        return field
-
     opt_state = state.opt_state
-    if jax.tree_util.tree_structure(opt_state) == pdef:
-        opt_state = arena_mod.tree_resident(table, opt_state)   # momentum
-    elif isinstance(opt_state, tuple) and opt_state:            # NamedTuple
-        opt_state = type(opt_state)(*(to_res(f) for f in opt_state))
-    return state._replace(
-        params=arena_mod.tree_resident(table, state.params),
-        opt_state=opt_state)
+    # params-shaped moment trees pack; anything else (scalar counters,
+    # empty states) passes through untouched
+    whole = jax.tree_util.tree_structure(opt_state) == pdef      # momentum
+    fields = (opt_state,) if whole else \
+        tuple(opt_state) if isinstance(opt_state, tuple) else ()  # NamedTuple
+    shaped = [jax.tree_util.tree_structure(f) == pdef for f in fields]
+    rows = iter(_pack_rows(acc, state.params, [state.params] + [
+        f for f, ok in zip(fields, shaped) if ok]))
+
+    def res(tree):
+        return arena_mod.tree_resident(table, tree, next(rows))
+
+    params = res(state.params)
+    if whole:
+        opt_state = res(opt_state)
+    elif any(shaped):
+        opt_state = type(opt_state)(*(res(f) if ok else f
+                                      for f, ok in zip(fields, shaped)))
+    return state._replace(params=params, opt_state=opt_state)
 
 
 def state_unresident(acc: DMDAccelerator, state):
@@ -136,15 +141,37 @@ def state_unresident(acc: DMDAccelerator, state):
     if state is None or not arena_mod.is_arena_state(state.params):
         return state
     table = acc.arena_for(state.params)
+    wrappers = [state.params] + [
+        x for x in jax.tree_util.tree_leaves(
+            state.opt_state, is_leaf=arena_mod.is_arena_state)
+        if arena_mod.is_arena_state(x)]
+    leaves = iter(_unpack_rows(acc, state.params, [
+        arena_mod.split_state(w)[0] for w in wrappers]))
 
     def unwrap(x):
-        return (arena_mod.tree_leafwise(table, x)
+        return (arena_mod.tree_leafwise(table, x, next(leaves))
                 if arena_mod.is_arena_state(x) else x)
 
     return state._replace(
-        params=arena_mod.tree_leafwise(table, state.params),
+        params=unwrap(state.params),
         opt_state=jax.tree_util.tree_map(
             unwrap, state.opt_state, is_leaf=arena_mod.is_arena_state))
+
+
+# The layout conversions run as one program per accelerator and state
+# signature; run eagerly under a mesh, every bucket's shard_map would
+# compile again on each call. Only the moved rows and leaves go through
+# the program: a leaf it merely passed through would come back a copy.
+@functools.partial(jax.jit, static_argnums=0)
+def _pack_rows(acc: DMDAccelerator, params, trees):
+    table = acc.arena_for(params)
+    return [arena_mod.pack_rows(table, t) for t in trees]
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _unpack_rows(acc: DMDAccelerator, params, arenas):
+    table = acc.arena_for(params)
+    return [arena_mod.unpack_rows(table, a) for a in arenas]
 
 
 def _accelerator_for(model, acfg, mesh, acc: Optional[DMDAccelerator]

@@ -54,7 +54,8 @@ import jax.numpy as jnp
 from repro.configs import get_config, reduced
 from repro.configs.base import DMDConfig, OptimizerConfig, TrainConfig
 from repro.data.tokens import batch_for_step
-from repro.distributed.sharding import mesh_context, set_mesh
+from repro.distributed.sharding import mesh_context
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import LanguageModel
 from repro.train import Trainer
 from repro.train.state import TrainState
@@ -91,7 +92,7 @@ def checksum(tree):
 
 def run_train(mesh_shape, axis_names, steps=6):
     acfg = small_acfg()
-    mesh = jax.make_mesh(mesh_shape, axis_names)
+    mesh = make_mesh(mesh_shape, axis_names)
     model = LanguageModel(acfg.model, head_tp=True, chunk_k=16)
     with mesh_context(mesh):
         trainer = Trainer(model, acfg, mesh=mesh)
@@ -131,7 +132,7 @@ def run_sharded_kernels():
     from repro.core import snapshots as snap
     from repro.kernels import ops, sharded
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     m = 5
     cfg = DMDConfig(m=m, s=8, tol=1e-4, anchor="first", warmup_steps=0,
                     cooldown_steps=0)
@@ -164,7 +165,7 @@ def run_sharded_kernels():
 
     ops.set_backend("pallas")                    # interpret-mode Pallas bodies
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             bufs = snap.init_buffers(params, cfg, plans)
             grams = snap.init_grams(bufs, cfg, plans)
 
@@ -274,7 +275,7 @@ def run_arena_sharded():
     from jax.sharding import NamedSharding
     from repro.core import DMDAccelerator, arena as arena_mod, leafplan
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     m = 5
     cfg = DMDConfig(m=m, s=8, tol=1e-3, anchor="first", warmup_steps=0,
                     cooldown_steps=0)
@@ -293,7 +294,7 @@ def run_arena_sharded():
     stack_dims = {"wqkv": 0, "A_log": 0, "w_gate": 0, "bias": 0,
                   "seg0": {"attn": {"wqkv": 1}}}
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         acc = DMDAccelerator(cfg, mesh=mesh, stack_dims=stack_dims)
         plans = acc.plans_for(params)
         table = acc.arena_for(params)
@@ -445,7 +446,7 @@ def run_controller_preempt(mode, argv):
         variant = argv[1]
         preempt_at = 5 if variant == "jump" else 7
         acfg = small_acfg(controller=True)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         model = LanguageModel(acfg.model, head_tp=True, chunk_k=16)
         with mesh_context(mesh):
             trainer = Trainer(model, acfg, mesh=mesh, checkpoint_dir=ckpt)
@@ -463,7 +464,7 @@ def run_controller_preempt(mode, argv):
     else:
         expected_step = int(argv[1])
         acfg = small_acfg(controller=True)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))   # REMAPPED topology
+        mesh = make_mesh((4, 2), ("data", "model"))   # REMAPPED topology
         model = LanguageModel(acfg.model, head_tp=True, chunk_k=16)
         with mesh_context(mesh):
             trainer = Trainer(model, acfg, mesh=mesh, checkpoint_dir=ckpt)
@@ -507,13 +508,13 @@ def main():
         print("CHECKSUM", f"{cs:.4f}")
     elif mode == "gram":
         from repro.core.dmd import gram_matrix
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(0)
         S = rng.normal(size=(6, 64, 32)).astype(np.float32)
         from jax.sharding import NamedSharding, PartitionSpec as P
         sharded = jax.device_put(
             S, NamedSharding(mesh, P(None, "data", "model")))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             g = jax.jit(lambda s: gram_matrix(s, anchor="first"))(sharded)
         flat = S.reshape(6, -1)
         flat = flat - flat[:1]
@@ -523,10 +524,10 @@ def main():
         assert err < 1e-5
     elif mode == "gradsync":
         from repro.distributed.gradsync import int8_psum_grads
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         rng = np.random.default_rng(0)
         g = {"w": jnp.asarray(rng.normal(size=(8, 8)), jnp.float32)}
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             synced = jax.jit(lambda t: int8_psum_grads(t, mesh))(g)
         # replicated input: mean over pods == input (up to int8 quantization)
         err = float(jnp.max(jnp.abs(synced["w"] - g["w"])))
@@ -536,7 +537,7 @@ def main():
     elif mode == "elastic_save":
         ckpt = sys.argv[2]
         acfg = small_acfg()
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         model = LanguageModel(acfg.model, head_tp=True, chunk_k=16)
         with mesh_context(mesh):
             trainer = Trainer(model, acfg, mesh=mesh, checkpoint_dir=ckpt)
@@ -549,7 +550,7 @@ def main():
         ckpt, variant = sys.argv[2], sys.argv[3]
         hetero = variant == "hetero"
         acfg = small_acfg(hetero)          # m=4 (+ norms m=3), warmup=2
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         model = LanguageModel(acfg.model, head_tp=True, chunk_k=16)
         with mesh_context(mesh):
             trainer = Trainer(model, acfg, mesh=mesh, checkpoint_dir=ckpt)
@@ -572,7 +573,7 @@ def main():
         from repro.core import dmd as dmd_mod
         from repro.core.leafplan import is_plan_leaf
         acfg = small_acfg(hetero)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))   # REMAPPED topology
+        mesh = make_mesh((4, 2), ("data", "model"))   # REMAPPED topology
         model = LanguageModel(acfg.model, head_tp=True, chunk_k=16)
         with mesh_context(mesh):
             trainer = Trainer(model, acfg, mesh=mesh, checkpoint_dir=ckpt)
@@ -611,7 +612,7 @@ def main():
         from repro.train.step import resident_enabled, state_resident
         ckpt = sys.argv[2]
         acfg = small_acfg()                       # adam: resident-capable
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         model = LanguageModel(acfg.model, head_tp=True, chunk_k=16)
         with mesh_context(mesh):
             trainer = Trainer(model, acfg, mesh=mesh, checkpoint_dir=ckpt)
@@ -636,7 +637,7 @@ def main():
         from repro.train.step import state_resident
         ckpt = sys.argv[2]
         acfg = small_acfg()
-        mesh = jax.make_mesh((4, 2), ("data", "model"))   # REMAPPED topology
+        mesh = make_mesh((4, 2), ("data", "model"))   # REMAPPED topology
         model = LanguageModel(acfg.model, head_tp=True, chunk_k=16)
         with mesh_context(mesh):
             trainer = Trainer(model, acfg, mesh=mesh, checkpoint_dir=ckpt)
@@ -669,7 +670,7 @@ def main():
     elif mode == "elastic_restore":
         ckpt = sys.argv[2]
         acfg = small_acfg()
-        mesh = jax.make_mesh((4, 2), ("data", "model"))   # DIFFERENT topology
+        mesh = make_mesh((4, 2), ("data", "model"))   # DIFFERENT topology
         model = LanguageModel(acfg.model, head_tp=True, chunk_k=16)
         with mesh_context(mesh):
             trainer = Trainer(model, acfg, mesh=mesh, checkpoint_dir=ckpt)

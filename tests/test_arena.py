@@ -116,6 +116,43 @@ def test_two_groups_two_buckets():
 # Kernel contract: segmented Pallas (interpret) vs reference vs per-leaf
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_segmented_kernels_on_stored_rows(dtype):
+    """Buffers are stored at snapshot_rows (m rounded up to the sublane
+    tile, zero rows below): every kernel, told the real m, matches the
+    reference on the unpadded m-row buffer. 11 blocks is not a multiple of
+    the query row's sublane tile, so the last q tile is ragged."""
+    rng = np.random.default_rng(4)
+    m, block_n, nb = 5, 128, 11
+    rows = ka.snapshot_rows(m, dtype)
+    assert rows > m and rows % 8 == 0
+    bs = np.asarray([0] * 3 + [1] * 6 + [2] * 2, np.int32)
+    x = jnp.asarray(rng.normal(size=(nb, m, block_n)), dtype)
+    q = jnp.asarray(rng.normal(size=(nb, block_n)), dtype)
+    xs = jnp.pad(x, ((0, 0), (0, rows - m), (0, 0)))
+    c = jnp.asarray(rng.normal(size=(3, m)), jnp.float32)
+    for anchor_first in (False, True):
+        np.testing.assert_allclose(
+            np.asarray(ka.gram_row_pallas(
+                xs, q, bs, 3, anchor_first=anchor_first, block_n=block_n,
+                m=m, interpret=True)),
+            np.asarray(ka.gram_row_ref(x, q, bs, 3,
+                                       anchor_first=anchor_first,
+                                       block_n=block_n)),
+            rtol=1e-5, atol=1e-4)
+    for anchor in ("first", "mean"):
+        kw = dict(anchor_first=anchor == "first",
+                  anchor_mean=anchor == "mean", block_n=block_n)
+        np.testing.assert_allclose(
+            np.asarray(ka.gram_pallas(xs, bs, 3, m=m, interpret=True, **kw)),
+            np.asarray(ka.gram_ref(x, bs, 3, **kw)), rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(
+        np.asarray(ka.combine_pallas(xs, c, bs, block_n=block_n,
+                                     interpret=True)),
+        np.asarray(ka.combine_ref(x, c, bs, block_n=block_n)),
+        rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("anchor_first", [False, True])
 def test_segmented_kernels_match_reference(anchor_first):
     rng = np.random.default_rng(1)
@@ -346,7 +383,7 @@ def test_arena_streaming_gram_equals_recompute():
     table = acc.arena_for(params)
     for key, b in table.items():
         full = ka.gram(bufs["__arena__"][key], b.block_sys(), b.n_sys,
-                       anchor_first=True, block_n=b.block_n)
+                       anchor_first=True, block_n=b.block_n, m=b.m)
         np.testing.assert_allclose(np.asarray(grams["__arena__"][key]),
                                    np.asarray(full), rtol=1e-5, atol=1e-5)
 

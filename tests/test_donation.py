@@ -118,3 +118,66 @@ def test_dropped_donation_is_caught():
                      state, donated=False)
     errors, _ = _audit(trainer, "train_step", t)
     assert errors, "donation pass failed to flag an undonated train step"
+
+
+def test_copy_scan_counts_buffer_copies_only():
+    """The copy scan behind the donation pass: a donated buffer that must
+    also be kept alive is copied at the top level and is flagged; a
+    layout change inside a fusion body (how the CPU backend feeds the
+    column-major LAPACK ``eigh`` from a row-major Gram it only reads)
+    materializes no buffer and is not."""
+    from repro.audit import hlo as H
+
+    g = jnp.zeros((16, 4, 4))
+    kept = jax.jit(lambda g, b: (g.at[0].set(b), g),
+                   donate_argnums=(0,)).lower(g, jnp.ones((4, 4)))
+    assert H.copy_ops(kept.compile().as_text(), {"f32[16,4,4]"})
+
+    def solve(g1, g2):                  # Grams pass through, as in dmd_step
+        gcat = jnp.concatenate([g1, g2])
+        w, v = jnp.linalg.eigh(gcat)
+        return g1, g2, w, v, jnp.diagonal(gcat, axis1=-2, axis2=-1)
+
+    hlo = jax.jit(solve, donate_argnums=(0, 1)).lower(
+        g, jnp.zeros((5, 4, 4))).compile().as_text()
+    assert H.copy_ops(hlo, {"f32[16,4,4]", "f32[5,4,4]"}) == []
+
+
+_FUSED_COPY_HLO = """\
+%fused_computation (param_0: f32[16,4,4], param_1: f32[4,4]) -> {out} {{
+  %param_0 = f32[16,4,4]{{2,1,0}} parameter(0)
+  %copy.1 = f32[16,4,4]{{2,1,0}} copy(%param_0)
+  %param_1 = f32[4,4]{{1,0}} parameter(1)
+  {body}
+}}
+
+ENTRY %main (g: f32[16,4,4], b: f32[4,4]) -> {out} {{
+  %g = f32[16,4,4]{{2,1,0}} parameter(0)
+  %b = f32[4,4]{{1,0}} parameter(1)
+  ROOT %fusion = {out} fusion(%g, %b), kind=kLoop, calls=%fused_computation
+}}
+"""
+
+
+@pytest.mark.parametrize("out,body", [
+    # multi-output fusion: the copy is an element of the ROOT tuple
+    ("(f32[16,4,4]{2,1,0}, f32[4,4]{1,0})",
+     "%neg = f32[4,4]{1,0} negate(%param_1)\n"
+     "  ROOT %tuple = (f32[16,4,4]{2,1,0}, f32[4,4]{1,0}) "
+     "tuple(%copy.1, %neg)"),
+    # dynamic-update-slice into the copied buffer: a new buffer of the
+    # donated shape leaves the fusion
+    ("f32[16,4,4]{2,1,0}",
+     "%c = s32[] constant(0)\n"
+     "  %r = f32[1,4,4]{2,1,0} reshape(%param_1)\n"
+     "  ROOT %dus = f32[16,4,4]{2,1,0} dynamic-update-slice(%copy.1, %r, "
+     "%c, %c, %c)"),
+], ids=["multi_output_tuple", "dynamic_update_slice"])
+def test_copy_scan_flags_fused_copies_that_leave_the_fusion(out, body):
+    """A copy inside a fusion body still counts when its buffer can leave
+    the fusion: as an element of a multi-output fusion's ROOT tuple, or
+    through a fusion whose output has the donated shape."""
+    from repro.audit import hlo as H
+
+    hlo = _FUSED_COPY_HLO.format(out=out, body=body)
+    assert H.copy_ops(hlo, {"f32[16,4,4]"}) == ["f32[16,4,4]"]

@@ -245,18 +245,21 @@ def test_serve_state_specs_cover_the_slot_table():
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.inputs import serve_state_specs
+    from repro.launch.mesh import make_mesh
 
     eng = _engine(n_slots=4)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     specs = serve_state_specs(eng._dstate, mesh)
     flat = {jax.tree_util.keystr(kp): s
             for kp, s in jax.tree_util.tree_flatten_with_path(specs)[0]}
     assert flat["['key']"] == P()
-    assert flat["['out_buf']"][0] == ("data",)
+    # entries compare as PartitionSpecs: JAX stores a one-axis tuple entry
+    # ("data",) as the bare axis name
+    assert P(flat["['out_buf']"][0]) == P(("data",))
     # cache k/v leaves: slot axis first, nothing on the garbage dims
     cache_specs = [s for p, s in flat.items() if "caches" in p]
     assert cache_specs, flat.keys()
     for s in cache_specs:
-        assert s[0] in (("data",), None)
+        assert P(s[0]) in (P(("data",)), P(None))
     # same structure as the decode state: shardings_of can map it 1:1
     jax.tree_util.tree_map(lambda a, b: None, specs, eng._dstate)
