@@ -152,35 +152,40 @@ def dmd_leaf_jump(cfg, plan: leafplan.LeafPlan, p, buf, gram, relax,
 
     nstack = plan.stack_dims
     anchor_first = cfg.anchor == "first"
-    if gram is None:
-        if plan.route == "pallas_shard_map" and plan.anchor_ok:
-            gram = sharded.gram(buf, plan, anchor_first=anchor_first)
-        elif plan.route == "pallas_flat" and plan.anchor_ok:
-            gram = ops.gram(buf, anchor_first=anchor_first,
-                            block_n=plan.block_n)
+    # profiler scopes: dmd_jump/solve and dmd_jump/combine under dmd_step
+    with jax.named_scope("solve"):
+        if gram is None:
+            if plan.route == "pallas_shard_map" and plan.anchor_ok:
+                gram = sharded.gram(buf, plan, anchor_first=anchor_first)
+            elif plan.route == "pallas_flat" and plan.anchor_ok:
+                gram = ops.gram(buf, anchor_first=anchor_first,
+                                block_n=plan.block_n)
+            else:
+                gram = dmd.gram_matrix(buf, anchor=cfg.anchor,
+                                       stack_dims=nstack,
+                                       upcast=cfg.gram_upcast)
+        s = plan.sched.s if plan.sched is not None else cfg.s
+        energy = plan.sched.energy if plan.sched is not None else 0.0
+        ridge = plan.sched.ridge if plan.sched is not None else 0.0
+        c, info = dmd.dmd_coefficients(
+            gram, s=s, tol=cfg.tol, mode=cfg.mode,
+            clamp_eigs=cfg.clamp_eigs, anchor=cfg.anchor,
+            affine=cfg.affine, trust_region=cfg.trust_region, relax=relax,
+            energy=energy, s_dyn=s_dyn, atol=getattr(cfg, "atol", 0.0),
+            ridge=ridge, ridge_dyn=ridge_dyn)
+    with jax.named_scope("combine"):
+        if plan.route == "pallas_shard_map":
+            w = sharded.combine(buf, c, plan)
+        elif plan.route == "pallas_flat":
+            w = ops.combine(buf, c, block_n=plan.block_n)
         else:
-            gram = dmd.gram_matrix(buf, anchor=cfg.anchor, stack_dims=nstack,
-                                   upcast=cfg.gram_upcast)
-    s = plan.sched.s if plan.sched is not None else cfg.s
-    energy = plan.sched.energy if plan.sched is not None else 0.0
-    ridge = plan.sched.ridge if plan.sched is not None else 0.0
-    c, info = dmd.dmd_coefficients(
-        gram, s=s, tol=cfg.tol, mode=cfg.mode,
-        clamp_eigs=cfg.clamp_eigs, anchor=cfg.anchor,
-        affine=cfg.affine, trust_region=cfg.trust_region, relax=relax,
-        energy=energy, s_dyn=s_dyn, atol=getattr(cfg, "atol", 0.0),
-        ridge=ridge, ridge_dyn=ridge_dyn)
-    if plan.route == "pallas_shard_map":
-        w = sharded.combine(buf, c, plan)
-    elif plan.route == "pallas_flat":
-        w = ops.combine(buf, c, block_n=plan.block_n)
-    else:
-        w = dmd.combine_snapshots(buf, c, stack_dims=nstack,
-                                  upcast=cfg.gram_upcast)
-    # Even c = e_last cannot save a non-finite BUFFER: the combine contracts
-    # every row, and 0 * inf = NaN. The jump must never leave params less
-    # finite than the last snapshot — fall back elementwise.
-    w = jnp.where(jnp.isfinite(w), w, buf[-1].astype(w.dtype))
+            w = dmd.combine_snapshots(buf, c, stack_dims=nstack,
+                                      upcast=cfg.gram_upcast)
+        # Even c = e_last cannot save a non-finite BUFFER: the combine
+        # contracts every row, and 0 * inf = NaN. The jump must never
+        # leave params less finite than the last snapshot — fall back
+        # elementwise.
+        w = jnp.where(jnp.isfinite(w), w, buf[-1].astype(w.dtype))
     return w.astype(p.dtype), jnp.mean(info["rank"].astype(jnp.float32))
 
 

@@ -712,28 +712,31 @@ def jump(cfg, table: Dict[str, ArenaBucket], params: PyTree,
         if groups is not None and gi not in groups:
             continue
         buckets = by_gi[gi]
-        grams = []
-        for b in buckets:
-            g = agrams.get(b.key) if agrams is not None else None
-            if g is None:
-                g = ka.gram(arenas[b.key], b.scope_block_sys(scope),
-                            b.scope_n_sys(scope),
-                            anchor_first=cfg.anchor == "first",
-                            anchor_mean=cfg.anchor == "mean",
-                            block_n=b.block_n, m=b.m, mesh=b.mesh,
-                            lane_axes=b.lane_axes, sys_axes=b.sys_axes)
-            grams.append(g)
-        gcat = grams[0] if len(grams) == 1 else jnp.concatenate(grams)
-        sched = buckets[0].sched
-        r = relax[gi] if per_group else relax
-        sd = None if s_vec is None else s_vec[gi]
-        rd = None if ridge_vec is None else ridge_vec[gi]
-        c, info = dmd_math.dmd_coefficients(
-            gcat, s=sched.s, tol=cfg.tol, mode=cfg.mode,
-            clamp_eigs=cfg.clamp_eigs, anchor=cfg.anchor, affine=cfg.affine,
-            trust_region=cfg.trust_region, relax=r, energy=sched.energy,
-            s_dyn=sd, atol=getattr(cfg, "atol", 0.0),
-            ridge=getattr(sched, "ridge", 0.0), ridge_dyn=rd)
+        # profiler scopes: dmd_jump/solve and dmd_jump/combine under
+        # dmd_step
+        with jax.named_scope("solve"):
+            grams = []
+            for b in buckets:
+                g = agrams.get(b.key) if agrams is not None else None
+                if g is None:
+                    g = ka.gram(arenas[b.key], b.scope_block_sys(scope),
+                                b.scope_n_sys(scope),
+                                anchor_first=cfg.anchor == "first",
+                                anchor_mean=cfg.anchor == "mean",
+                                block_n=b.block_n, m=b.m, mesh=b.mesh,
+                                lane_axes=b.lane_axes, sys_axes=b.sys_axes)
+                grams.append(g)
+            gcat = grams[0] if len(grams) == 1 else jnp.concatenate(grams)
+            sched = buckets[0].sched
+            r = relax[gi] if per_group else relax
+            sd = None if s_vec is None else s_vec[gi]
+            rd = None if ridge_vec is None else ridge_vec[gi]
+            c, info = dmd_math.dmd_coefficients(
+                gcat, s=sched.s, tol=cfg.tol, mode=cfg.mode,
+                clamp_eigs=cfg.clamp_eigs, anchor=cfg.anchor,
+                affine=cfg.affine, trust_region=cfg.trust_region, relax=r,
+                energy=sched.energy, s_dyn=sd, atol=getattr(cfg, "atol", 0.0),
+                ridge=getattr(sched, "ridge", 0.0), ridge_dyn=rd)
         ofs = 0
         for b in buckets:
             lead = b.gram_lead(scope)
@@ -752,14 +755,18 @@ def jump(cfg, table: Dict[str, ArenaBucket], params: PyTree,
                 ).astype(jnp.float32))
 
             buf = arenas[b.key]
-            flat = ka.combine(buf, cb, b.scope_block_sys(scope),
-                              block_n=b.block_n, mesh=b.mesh,
-                              lane_axes=b.lane_axes, sys_axes=b.sys_axes)
-            # Same last line of defense as the per-leaf route: a non-finite
-            # BUFFER poisons the combine even under c = e_last (0*inf=NaN);
-            # never leave params less finite than the last snapshot.
-            flat = jnp.where(jnp.isfinite(flat), flat,
-                             buf[:, b.m - 1, :].reshape(-1).astype(flat.dtype))
+            with jax.named_scope("combine"):
+                flat = ka.combine(buf, cb, b.scope_block_sys(scope),
+                                  block_n=b.block_n, mesh=b.mesh,
+                                  lane_axes=b.lane_axes,
+                                  sys_axes=b.sys_axes)
+                # Same last line of defense as the per-leaf route: a
+                # non-finite BUFFER poisons the combine even under
+                # c = e_last (0*inf=NaN); never leave params less finite
+                # than the last snapshot.
+                flat = jnp.where(jnp.isfinite(flat), flat,
+                                 buf[:, b.m - 1, :].reshape(-1).astype(
+                                     flat.dtype))
             if resident:
                 updates[b.key] = flat.astype(
                     jnp.dtype(b.segments[0].param_dtype))

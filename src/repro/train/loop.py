@@ -235,6 +235,55 @@ class Trainer:
         the validation split is preferred even over an explicit
         `eval_batch`. The gate NEVER draws from the training iterator.
         Sliced to controller.eval_rows rows (clamped to the batch size)."""
+        # Host spans (jax.profiler.TraceAnnotation): written into the
+        # profiler's trace only while one is taken, on the clock of the
+        # device ops; nearly free otherwise.
+        with jax.profiler.TraceAnnotation("repro.fit.enter"):
+            state, start_step, eval_batch = self._enter(state, eval_batch)
+        ckpt_every = self.acfg.train.checkpoint_every
+        for step in range(start_step, steps):
+            with jax.profiler.StepTraceAnnotation("repro.fit.step",
+                                                  step_num=step):
+                if self.fail_at_step is not None \
+                        and step == self.fail_at_step:
+                    raise RuntimeError(f"injected failure at step {step}")
+                with jax.profiler.TraceAnnotation("repro.fit.batch"):
+                    batch = next(batches)
+                with jax.profiler.TraceAnnotation("repro.fit.train_step"):
+                    state, metrics = self.train_step(
+                        state, batch, jnp.asarray(step, jnp.int32))
+                apply_groups = (self.acc.apply_groups(step)
+                                if self.acfg.dmd.enabled else ())
+                if apply_groups:
+                    with jax.profiler.TraceAnnotation("repro.fit.jump",
+                                                      groups=apply_groups):
+                        state, dmd_info = self._jump(state, step,
+                                                     apply_groups,
+                                                     eval_batch)
+                    metrics.update(dmd_info)
+                with jax.profiler.TraceAnnotation("repro.fit.on_metrics"):
+                    if log_every and step % log_every == 0:
+                        loss = float(metrics["loss"])
+                        print(f"step {step}: loss={loss:.6f}")
+                    if on_metrics is not None:
+                        on_metrics(step, metrics)
+                if ckpt_every and (step + 1) % ckpt_every == 0:
+                    with jax.profiler.TraceAnnotation(
+                            "repro.fit.checkpoint"):
+                        self.save(state, step + 1)
+                if self._preempted:
+                    with jax.profiler.TraceAnnotation(
+                            "repro.fit.checkpoint"):
+                        self.save(state, step + 1)
+                    print(f"preempted: checkpoint saved at step {step + 1}")
+                    break
+        with jax.profiler.TraceAnnotation("repro.fit.exit"):
+            return state_unresident(self.acc, state)
+
+    def _enter(self, state: Optional[TrainState],
+               eval_batch: Optional[PyTree]) -> tuple:
+        """fit's set-up: (the resident state to train from, its step
+        index, the gate's batch)."""
         self._install_preempt_handler()
         resumed = self.restore(state)
         if resumed is not None:
@@ -248,62 +297,44 @@ class Trainer:
         # state_leafwise in save) never see the wrapper layout.
         state = state_resident(self.acc, self.acfg, state)
         start_step = int(state.step)
-        ckpt_every = self.acfg.train.checkpoint_every
+        if not self.controller_on:
+            return state, start_step, eval_batch
+        ccfg = self.acfg.dmd.controller
+        # The ISSUE 9 bugfix: the old fallback `eval_batch =
+        # next(batches)` consumed (and scored on) the next TRAINING
+        # batch — the gate then measured training-trajectory fit, not
+        # generalization, and the stream position shifted by one.
+        if getattr(ccfg, "val_gate", False) and self.val_batch is not None:
+            eval_batch = self.val_batch
+        elif eval_batch is None:
+            eval_batch = self.val_batch
+        if eval_batch is None:
+            raise ValueError(
+                "controller mode needs a gate batch disjoint from the "
+                "training stream: pass fit(eval_batch=...) or "
+                "Trainer(val_batch=...) (vocab models carve one "
+                "automatically at init)")
+        rows = ccfg.eval_rows
+        if rows:
+            # clamp to the actual batch size — eval_rows larger than
+            # the batch must not silently slice past it
+            n_rows = min(int(x.shape[0]) for x in
+                         jax.tree_util.tree_leaves(eval_batch))
+            rows = min(int(rows), n_rows)
+            eval_batch = jax.tree_util.tree_map(
+                lambda x: x[:rows], eval_batch)
+        return state, start_step, eval_batch
 
+    def _jump(self, state: TrainState, step: int, groups: tuple,
+              eval_batch: Optional[PyTree]) -> tuple:
+        """Dispatch the jump of the groups whose window closed after
+        ``step``, and publish what it did not reject."""
+        relax = jnp.asarray(self.acc.relax_vector(step), jnp.float32)
         if self.controller_on:
-            ccfg = self.acfg.dmd.controller
-            # The ISSUE 9 bugfix: the old fallback `eval_batch =
-            # next(batches)` consumed (and scored on) the next TRAINING
-            # batch — the gate then measured training-trajectory fit, not
-            # generalization, and the stream position shifted by one.
-            if getattr(ccfg, "val_gate", False) and self.val_batch is not None:
-                eval_batch = self.val_batch
-            elif eval_batch is None:
-                eval_batch = self.val_batch
-            if eval_batch is None:
-                raise ValueError(
-                    "controller mode needs a gate batch disjoint from the "
-                    "training stream: pass fit(eval_batch=...) or "
-                    "Trainer(val_batch=...) (vocab models carve one "
-                    "automatically at init)")
-            rows = ccfg.eval_rows
-            if rows:
-                # clamp to the actual batch size — eval_rows larger than
-                # the batch must not silently slice past it
-                n_rows = min(int(x.shape[0]) for x in
-                             jax.tree_util.tree_leaves(eval_batch))
-                rows = min(int(rows), n_rows)
-                eval_batch = jax.tree_util.tree_map(
-                    lambda x: x[:rows], eval_batch)
-
-        for step in range(start_step, steps):
-            if self.fail_at_step is not None and step == self.fail_at_step:
-                raise RuntimeError(f"injected failure at step {step}")
-            batch = next(batches)
-            state, metrics = self.train_step(state, batch,
-                                             jnp.asarray(step, jnp.int32))
-            apply_groups = (self.acc.apply_groups(step)
-                            if self.acfg.dmd.enabled else ())
-            if apply_groups:
-                relax = jnp.asarray(self.acc.relax_vector(step), jnp.float32)
-                if self.controller_on:
-                    state, dmd_info = self.dmd_step(state, relax, eval_batch,
-                                                    groups=apply_groups)
-                else:
-                    state, dmd_info = self.dmd_step(state, relax,
-                                                    groups=apply_groups)
-                metrics.update(dmd_info)
-                if self.on_publish is not None:
-                    self._publish(state, dmd_info, step + 1)
-            if log_every and step % log_every == 0:
-                loss = float(metrics["loss"])
-                print(f"step {step}: loss={loss:.6f}")
-            if on_metrics is not None:
-                on_metrics(step, metrics)
-            if ckpt_every and (step + 1) % ckpt_every == 0:
-                self.save(state, step + 1)
-            if self._preempted:
-                self.save(state, step + 1)
-                print(f"preempted: checkpoint saved at step {step + 1}")
-                break
-        return state_unresident(self.acc, state)
+            state, dmd_info = self.dmd_step(state, relax, eval_batch,
+                                            groups=groups)
+        else:
+            state, dmd_info = self.dmd_step(state, relax, groups=groups)
+        if self.on_publish is not None:
+            self._publish(state, dmd_info, step + 1)
+        return state, dmd_info

@@ -213,10 +213,14 @@ def make_train_step(model, acfg, *, mesh=None, global_batch=None,
         resident = arena_mod.is_arena_state(params)
         table = acc.arena_for(params) if resident else None
 
+        # Profiler scopes (metadata only): the forward shows in the HLO
+        # op_name as jvp(forward), the backward as transpose(jvp(forward)).
         def one_loss(p, mb):
-            if resident:
-                p = arena_mod.tree_leafwise(table, p)
-            return _loss(p, mb)
+            with jax.named_scope("forward"):
+                if resident:
+                    with jax.named_scope("arena_views"):
+                        p = arena_mod.tree_leafwise(table, p)
+                return _loss(p, mb)
 
         if ga > 1:
             def reshape_mb(x):
@@ -242,21 +246,20 @@ def make_train_step(model, acfg, *, mesh=None, global_batch=None,
             grads = jax.tree_util.tree_map(
                 lambda g: g.astype(jnp.float32), grads)
 
-        if acfg.parallel.grad_compression == "int8" and mesh is not None \
-                and "pod" in mesh.axis_names:
-            from repro.distributed.gradsync import int8_psum_grads
-            grads = int8_psum_grads(grads, mesh)
-
-        updates, opt_state = opt.update(grads, state.opt_state, params,
-                                        state.step)
-        params = apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            if acfg.parallel.grad_compression == "int8" \
+                    and mesh is not None and "pod" in mesh.axis_names:
+                from repro.distributed.gradsync import int8_psum_grads
+                grads = int8_psum_grads(grads, mesh)
+            updates, opt_state = opt.update(grads, state.opt_state, params,
+                                            state.step)
+            params = apply_updates(params, updates)
 
         buffers, grams = state.dmd_buffers, state.dmd_gram
         if dmd_on and buffers is not None:
             streaming = streaming_on and grams is not None
             plans = acc.plans_for(params)       # trace-time, cached
             table = acc.arena_for(params)       # {} when arenas are off
-            slots = sched_mod.slots_for_step(acc.groups, step)
             # per-leaf snapshot/Gram calls only see the non-packed leaves;
             # with resident params that is the wrapper's leaf subtree
             # (None at every packed path — compile-time pass-throughs)
@@ -270,39 +273,42 @@ def make_train_step(model, acfg, *, mesh=None, global_batch=None,
             # Arena'd leaves ride the packed route (one gather + one row
             # update + one segmented Gram launch per bucket); the per-leaf
             # code below only sees the leaves the arena could not take.
-            for gi in range(len(acc.groups)):
-                def write(args, gi=gi):
-                    bufs, g = args
-                    slot = jnp.maximum(slots[gi], 0)
-                    if arena_mod.is_arena_state(bufs):
-                        arenas, leaf = arena_mod.split_state(bufs)
-                        arenas = arena_mod.record(arenas, params, slot,
-                                                  table, acfg.dmd, group=gi)
-                        leaf = snap.record(leaf, p_leaf, slot, plans,
-                                           group=gi)
-                        bufs = arena_mod.make_state(arenas, leaf)
-                        if streaming:
-                            ag, lg = arena_mod.split_state(g)
+            def write(args, gi):
+                bufs, g = args
+                slot = jnp.maximum(slots[gi], 0)
+                if arena_mod.is_arena_state(bufs):
+                    arenas, leaf = arena_mod.split_state(bufs)
+                    arenas = arena_mod.record(arenas, params, slot, table,
+                                              acfg.dmd, group=gi)
+                    leaf = snap.record(leaf, p_leaf, slot, plans, group=gi)
+                    bufs = arena_mod.make_state(arenas, leaf)
+                    if streaming:
+                        ag, lg = arena_mod.split_state(g)
+                        with jax.named_scope("gram_row"):
                             g = arena_mod.make_state(
                                 arena_mod.update_grams(ag, arenas, slot,
                                                        acfg.dmd, table,
                                                        group=gi),
                                 snap.update_grams(lg, leaf, p_leaf, slot,
                                                   acfg.dmd, plans, group=gi))
-                        return bufs, g
-                    bufs = snap.record(bufs, params, slot, plans, group=gi)
-                    if streaming:
+                    return bufs, g
+                bufs = snap.record(bufs, params, slot, plans, group=gi)
+                if streaming:
+                    with jax.named_scope("gram_row"):
                         g = snap.update_grams(g, bufs, params, slot,
                                               acfg.dmd, plans, group=gi)
-                    return bufs, g
-                buffers, grams = jax.lax.cond(slots[gi] >= 0, write,
-                                              lambda a: a, (buffers, grams))
+                return bufs, g
+
+            with jax.named_scope("dmd_record"):
+                slots = sched_mod.slots_for_step(acc.groups, step)
+                for gi in range(len(acc.groups)):
+                    buffers, grams = jax.lax.cond(
+                        slots[gi] >= 0, functools.partial(write, gi=gi),
+                        lambda a: a, (buffers, grams))
 
         new_state = TrainState(params, opt_state, state.step + 1, buffers,
                                grams, state.controller)
-        gnorm = jnp.sqrt(sum(jnp.vdot(g, g)
-                             for g in jax.tree_util.tree_leaves(grads)))
-        return new_state, {"loss": loss, "grad_norm": gnorm}
+        return new_state, {"loss": loss}
 
     return train_step
 
@@ -449,18 +455,19 @@ def make_dmd_step(acfg, *, mesh=None, acc: Optional[DMDAccelerator] = None,
             if grams is None or not streaming_on:
                 grams = _none_like(state.dmd_buffers)
             plans = acc.plans_for(state.params)
-            params, mean_rank = jump_tree(cfg, plans, state.params,
-                                          state.dmd_buffers, grams, relax,
-                                          groups=groups,
-                                          arena=acc.arena_for(state.params))
-            opt_state = state.opt_state
-            # the jump teleports the jumped groups' weights; reset those
-            # groups' moments — unless the group opts out (sched.reset_opt)
-            reset = acc.reset_groups(groups)
-            if reset:
-                opt_state = reset_opt_state_after_jump(
-                    opt, state.opt_state, params, plans, reset, acc.n_groups,
-                    arena=acc.arena_for(params))
+            with jax.named_scope("dmd_jump"):
+                params, mean_rank = jump_tree(
+                    cfg, plans, state.params, state.dmd_buffers, grams,
+                    relax, groups=groups, arena=acc.arena_for(state.params))
+                opt_state = state.opt_state
+                # the jump teleports the jumped groups' weights; reset
+                # those groups' moments — unless the group opts out
+                # (sched.reset_opt)
+                reset = acc.reset_groups(groups)
+                if reset:
+                    opt_state = reset_opt_state_after_jump(
+                        opt, state.opt_state, params, plans, reset,
+                        acc.n_groups, arena=acc.arena_for(params))
             new_state = TrainState(params, opt_state, state.step,
                                    state.dmd_buffers, state.dmd_gram,
                                    state.controller)
@@ -493,6 +500,10 @@ def make_dmd_step(acfg, *, mesh=None, acc: Optional[DMDAccelerator] = None,
 
     def gated_dmd_step(state: TrainState, relax, eval_batch,
                        groups: Optional[Sequence[int]] = None) -> tuple:
+        with jax.named_scope("dmd_jump"):
+            return gated_jump(state, relax, eval_batch, groups)
+
+    def gated_jump(state, relax, eval_batch, groups):
         zero = jnp.zeros((), jnp.float32)
         if state.dmd_buffers is None:
             return state, {"mean_rank": zero, "ctrl_outcome":
@@ -512,9 +523,10 @@ def make_dmd_step(acfg, *, mesh=None, acc: Optional[DMDAccelerator] = None,
         table = acc.arena_for(state.params) if resident else None
 
         def eval_loss(p):
-            if resident:
-                p = arena_mod.tree_leafwise(table, p)
-            return _loss(p, eval_batch)
+            with jax.named_scope("gate"):
+                if resident:
+                    p = arena_mod.tree_leafwise(table, p)
+                return _loss(p, eval_batch)
 
         # Candidate jump at the adapted horizon, relax tempered by the
         # per-group effective scale. `relax` may be scalar or (n_groups,);
@@ -594,9 +606,10 @@ def make_dmd_step(acfg, *, mesh=None, acc: Optional[DMDAccelerator] = None,
 
             return attempt
 
-        params, opt_state, outcome, loss_final, level = jax.lax.cond(
-            ctrl_mod.gate_outcome(loss_pre, loss_post, ccfg.accept_tol),
-            accept_full, try_levels(0), None)
+        with jax.named_scope("gate"):
+            params, opt_state, outcome, loss_final, level = jax.lax.cond(
+                ctrl_mod.gate_outcome(loss_pre, loss_post, ccfg.accept_tol),
+                accept_full, try_levels(0), None)
 
         gain = (loss_pre - loss_final) / jnp.maximum(loss_pre, 1e-30)
         new_ctrl = ctrl_mod.update_on_jump(ctrl, jumped, outcome, gain,
