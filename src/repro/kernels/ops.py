@@ -1,7 +1,6 @@
 """Backend dispatch for the DMD data-pass kernels (DESIGN.md §3).
 
-Every public entry point (`gram`, `gram_row`, `combine`, `flash_attention`)
-routes by backend:
+Every DMD entry point (`gram`, `gram_row`, `combine`) routes by backend:
 
   * TPU  -> the Pallas kernels, COMPILED (interpret=False). The seed
     hard-wired interpret mode everywhere, so the kernels never actually
@@ -9,6 +8,9 @@ routes by backend:
   * CPU/GPU -> the pure `dot_general` references in `ref.py`. These are the
     correctness oracles and XLA already emits optimal code for them; running
     the Pallas interpreter on CPU would be strictly slower.
+
+`flash_attention` is the Pallas flash kernel on every backend (interpreted
+off the TPU); `models/attention.py::attend` routes attention itself.
 
 `interpret=True` may still be passed explicitly to force the Pallas kernel
 body through the interpreter on any backend — that is the kernel-vs-oracle
@@ -22,11 +24,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref
+from repro.kernels import flash, ref
 from repro.kernels.gram import gram_pallas
 from repro.kernels.gram_row import gram_row_pallas
 from repro.kernels.combine import combine_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
 
 _FORCED_BACKEND: Optional[str] = None
 
@@ -132,13 +133,11 @@ def combine(snapshots: jnp.ndarray, c: jnp.ndarray, *, block_n: int = 2048,
     return out.reshape(snapshots.shape[1:])
 
 
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    tq: int = 128, tk: int = 128, interpret=None):
-    if _route(interpret) == "ref":
-        heads, kv_heads = q.shape[2], k.shape[2]
-        if kv_heads != heads:                    # the oracle has no GQA path
-            k = jnp.repeat(k, heads // kv_heads, axis=2)
-            v = jnp.repeat(v, heads // kv_heads, axis=2)
-        return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
-    return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                  tq=tq, tk=tk, interpret=_interp(interpret))
+def flash_attention(q, k, v, *, causal: bool = True, interpret=None):
+    """(B, Sq, H, d), (B, Sk, K, d) -> (B, Sq, H, d); differentiable: the
+    Pallas flash kernel with its backward pass (kernels/flash.py).
+
+    It has no reference route: `models/attention.py::attend` chooses
+    between it and its own jnp core, which is the kernel's oracle."""
+    return flash.flash_attention(q, k, v, causal=causal,
+                                 interpret=_interp(interpret))

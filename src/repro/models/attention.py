@@ -1,31 +1,51 @@
 """Attention: GQA with RoPE/M-RoPE, sliding windows, KV caches.
 
-One blockwise (online-softmax, kv-chunked) core serves train, prefill and
-decode. It is sharding-agnostic jnp: callers set sharding via constraints.
+Two attention cores, chosen per call by what the call can observe:
+
+  * the Pallas flash kernel with its backward pass (kernels/flash.py via
+    `kernels.ops.flash_attention`) on a TPU, for attention with no cache
+    over whole sequences: training forwards (self- and cross-attention),
+    the DMD gate's forwards, and cross-attention wherever its shapes
+    qualify. It needs queries from position 0 of the keys (no cache, no
+    `kv_len`, no `k_positions`, `q_offset == 0`), no sliding window (it has
+    no window mask), Sq and Sk of at least one kernel block
+    (`flash.LANES`), and queries whose dQ fits the backward's VMEM
+    (`flash.fits`: up to 8192 at head dim 128 in bf16). It keeps the scores
+    in VMEM and saves none of them for the backward pass.
+  * the blockwise jnp core (`blockwise_attention`: online softmax, kv
+    chunks of `chunk_k`) everywhere else: decode and cached prefill, ring
+    caches, local windows, reduced configs shorter than a block, query
+    sequences too long for the backward's VMEM, and every backend but the
+    TPU. It is also the kernel's oracle.
+
+Both run under `jax.named_scope("attention")`.
 
 Two distribution layouts (selected per arch by head divisibility; see
 DESIGN.md §6):
   * head-TP:    q/k/v sharded on the head dim over "model". Zero attention
                 collectives. Requires n_heads % tp == 0 (and kv likewise, or
-                kv replicated when n_kv < tp).
+                kv replicated when n_kv < tp). On a mesh with more than one
+                device the kernel runs per shard under `shard_map` (batch on
+                the batch axes, heads on "model"); a Pallas call cannot be
+                partitioned by GSPMD.
   * kv-SP:      heads replicated over "model"; K/V sharded on the SEQUENCE
                 dim. The softmax statistics and the PV contraction reduce over
                 the sharded dim, so GSPMD emits exactly the flash-decoding
                 partial-softmax pattern (two small all-reduces). Works for any
-                head count; also the long_500k decode layout.
-
-The Pallas flash-attention kernel (repro.kernels.flash_attention) implements
-the same contract for the TPU hot path; this jnp version is its oracle and
-the lowering default.
+                head count; also the long_500k decode layout. Always the jnp
+                core.
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import constrain
+from repro.distributed.sharding import batch_axes, constrain, current_mesh
+from repro.kernels import flash, ops
 from repro.models import layers
 
 NEG_INF = -1e30
@@ -119,6 +139,35 @@ def blockwise_attention(q, k, v, *, causal: bool, window: int = 0,
     out = acc / jnp.maximum(l[..., None], 1e-30)
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, hd)
     return out.astype(q.dtype)
+
+
+def _flash_core(q, k, v, *, causal: bool, head_sharded: bool):
+    """The Pallas flash kernel where it applies, else None (the caller's
+    jnp core): a TPU, Sq and Sk of at least one kernel block, a dQ that
+    fits the backward's VMEM, and on a mesh of more than one device the
+    head-TP layout with heads and batch that divide it (the kernel then
+    runs per shard)."""
+    B, Sq, H, d = q.shape
+    if (ops.active_backend() != "pallas" or min(Sq, k.shape[1]) < flash.LANES
+            or not flash.fits(Sq, d, q.dtype)):
+        return None
+    attend_fn = lambda q, k, v: ops.flash_attention(q, k, v, causal=causal)
+    mesh = current_mesh()
+    sizes = dict(mesh.shape) if mesh is not None else {}
+    axes = batch_axes(mesh)
+    n_batch = math.prod(sizes.get(a, 1) for a in axes)
+    n_model = sizes.get("model", 1)
+    if n_batch * n_model == 1:
+        return attend_fn(q, k, v)
+    if not head_sharded or H % n_model or B % n_batch:
+        return None
+    K = k.shape[2]
+    if K != H:                       # each head shard needs its kv heads
+        k = jnp.repeat(k, H // K, axis=2)
+        v = jnp.repeat(v, H // K, axis=2)
+    spec = P(axes, None, "model", None)
+    return jax.shard_map(attend_fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 class KVCache(NamedTuple):
@@ -292,9 +341,16 @@ def attend(x, p, cfg, *, positions, causal=True, window=0,
             kv_len = start + S
             q_offset = start
 
-    out = blockwise_attention(q, k, v, causal=causal, window=window,
-                              q_offset=q_offset, kv_len=kv_len,
-                              k_positions=k_positions, chunk_k=chunk_k)
+    with jax.named_scope("attention"):
+        out = None
+        if cache is None and not window:   # whole sequences from position 0
+            out = _flash_core(q, k, v, causal=causal,
+                              head_sharded=head_tp or pad_rep is not None)
+        if out is None:
+            out = blockwise_attention(q, k, v, causal=causal, window=window,
+                                      q_offset=q_offset, kv_len=kv_len,
+                                      k_positions=k_positions,
+                                      chunk_k=chunk_k)
     if pad_rep is not None:                 # drop the padded q heads
         K_, rep, rep_pad = pad_rep
         out = out.reshape(B, S, K_, rep_pad, hd)[:, :, :, :rep]

@@ -38,6 +38,11 @@ Modes (argv[1]):
                               against the new mesh, re-residentizes into
                               the new mesh's buckets, and one more fit
                               step runs on the resident state; RESIDENT_OK
+  flash_shard_map             a reduced float32 whisper-base's loss and
+                              grads on a (2,2) mesh, head-TP: the forced
+                              kernel route (the flash kernel per shard
+                              under shard_map, interpret mode) against the
+                              jnp route; FLASH_ERR
 """
 import os
 import sys
@@ -494,6 +499,38 @@ def run_controller_preempt(mode, argv):
         print("CTRL_OK", jumps_total)
 
 
+def run_flash_shard_map():
+    from repro.kernels import ops
+    acfg = get_config("whisper-base")
+    mc = dataclasses.replace(
+        reduced(acfg.model, encoder_seq_len=160, max_seq_len=128),
+        dtype="float32")
+    model = LanguageModel(mc, head_tp=True, chunk_k=64)
+    params = model.init(jax.random.PRNGKey(0))
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 128), 0,
+                                          mc.vocab_size),
+             "frames": jax.random.normal(jax.random.PRNGKey(2),
+                                         (4, 160, mc.d_model))}
+    mesh = make_mesh((2, 2), ("data", "model"))
+
+    def run():           # a new function each time: JAX caches by function
+        f = jax.value_and_grad(lambda p: model.loss(p, batch)[0])
+        with mesh_context(mesh):
+            jaxpr = str(jax.make_jaxpr(f)(params))
+            return jax.jit(f)(params), jaxpr.count("shard_map")
+
+    (l_jnp, g_jnp), n_jnp = run()
+    ops.set_backend("pallas")
+    (l_ker, g_ker), n_ker = run()
+    assert (n_jnp, n_ker) == (0, 9), (n_jnp, n_ker)
+    err = max([abs(float(l_ker) - float(l_jnp)) / abs(float(l_jnp))] + [
+        float(np.abs(np.asarray(a) - np.asarray(b)).max()
+              / np.abs(np.asarray(b)).max())
+        for a, b in zip(jax.tree_util.tree_leaves(g_ker),
+                        jax.tree_util.tree_leaves(g_jnp))])
+    print("FLASH_ERR", err)
+
+
 def main():
     mode = sys.argv[1]
     if mode == "train":
@@ -665,6 +702,8 @@ def main():
         run_controller_preempt(mode, sys.argv[2:])
     elif mode == "sharded_kernels":
         run_sharded_kernels()
+    elif mode == "flash_shard_map":
+        run_flash_shard_map()
     elif mode == "arena_sharded":
         run_arena_sharded()
     elif mode == "elastic_restore":

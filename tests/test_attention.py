@@ -89,3 +89,66 @@ def test_ring_cache_positions():
     cache = init_ring_cache(1, 4, 2, 4, jnp.float32)
     assert cache.pos.shape == (4,)
     assert int(cache.pos[0]) == -1
+
+
+def test_reduced_whisper_kernel_route_matches_jnp_route():
+    """A reduced whisper-base (float32) gives the same loss and gradients
+    with the kernel route forced (every self- and cross-attention through
+    the Pallas flash kernel, in interpret mode) as with the jnp route. The
+    lengths take several blocks on both axes: 1600 frames pad to 13 blocks
+    of 128, and the 2048 causal tokens make 4 q blocks of 512 by 2 k
+    blocks of 1024, one of them skipped."""
+    import dataclasses
+    from repro.kernels import ops
+
+    acfg = get_config("whisper-base")
+    mc = dataclasses.replace(
+        reduced(acfg.model, encoder_seq_len=1600, max_seq_len=2048),
+        dtype="float32")
+    model = LanguageModel(mc, head_tp=False, chunk_k=64)
+    params = model.init(jax.random.PRNGKey(0))
+    B, S = 1, 2048
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,
+                                          mc.vocab_size),
+             "frames": jax.random.normal(jax.random.PRNGKey(2),
+                                         (B, 1600, mc.d_model))}
+
+    def run():           # a new function each time: JAX caches by function
+        loss_and_grad = jax.value_and_grad(lambda p: model.loss(p, batch)[0])
+        jaxpr = str(jax.make_jaxpr(loss_and_grad)(params))
+        return jax.jit(loss_and_grad)(params), jaxpr.count("pallas_call")
+
+    (l_jnp, g_jnp), n_jnp = run()
+    ops.set_backend("pallas")
+    try:
+        (l_ker, g_ker), n_ker = run()
+    finally:
+        ops.set_backend(None)
+    # forward and backward kernels of the three attentions (layers scanned)
+    assert (n_jnp, n_ker) == (0, 6)
+    np.testing.assert_allclose(float(l_ker), float(l_jnp), rtol=1e-5)
+    for path, a in jax.tree_util.tree_leaves_with_path(g_ker):
+        b = np.asarray(dict(jax.tree_util.tree_leaves_with_path(g_jnp))[path])
+        np.testing.assert_allclose(np.asarray(a), b,
+                                   atol=1e-4 * np.abs(b).max(),
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("S,d,kernel", [(8192, 128, True), (8320, 128, False),
+                                        (4096, 256, True), (4224, 256, False),
+                                        (100, 64, False)])
+def test_flash_route_bounds(S, d, kernel):
+    """With the Pallas backend, whole-sequence attention takes the flash
+    kernel from one block (128) up to the longest bf16 query sequence
+    whose dQ fits the backward's VMEM, and the jnp core outside that."""
+    from repro.kernels import ops
+    from repro.models.attention import _flash_core
+
+    x = jax.ShapeDtypeStruct((1, S, 2, d), jnp.bfloat16)
+    ops.set_backend("pallas")
+    try:
+        out = jax.eval_shape(lambda q, k, v: _flash_core(
+            q, k, v, causal=True, head_sharded=False), x, x, x)
+    finally:
+        ops.set_backend(None)
+    assert (out is not None) == kernel

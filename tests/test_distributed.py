@@ -56,6 +56,14 @@ def test_sharded_gram_matches_numpy():
     assert err < 1e-5
 
 
+def test_flash_shard_map_matches_jnp_route():
+    """On a (2,2) mesh the flash kernel runs per shard under shard_map
+    (batch on data, heads on model) and gives the jnp core's loss and
+    gradients."""
+    out = run_worker("flash_shard_map", ndev="4")
+    assert float(_parse("FLASH_ERR", out)[0]) < 1e-4
+
+
 def test_int8_cross_pod_gradsync():
     out = run_worker("gradsync")
 

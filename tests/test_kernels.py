@@ -178,25 +178,62 @@ def test_combine_multidim():
                                   ).reshape(8, 12), rtol=1e-5)
 
 
-@pytest.mark.parametrize("B,Sq,Sk,H,K,d,causal,window", [
-    (1, 128, 128, 4, 4, 64, True, 0),
-    (2, 256, 256, 4, 2, 64, True, 0),
-    (1, 256, 256, 2, 2, 64, True, 64),
-    (1, 100, 100, 2, 1, 32, False, 0),
-    (1, 64, 192, 2, 2, 128, True, 0),          # Sq != Sk
+@pytest.mark.parametrize("B,Sq,Sk,H,K,d,causal", [
+    (1, 128, 128, 4, 4, 64, True),
+    (2, 256, 256, 4, 2, 64, True),
+    (1, 640, 1664, 2, 1, 64, True),           # 5 q x 13 k blocks, skips
+    (1, 100, 100, 2, 1, 32, False),
+    (1, 64, 192, 2, 2, 128, True),            # Sq != Sk
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_kernel(B, Sq, Sk, H, K, d, causal, window, dtype):
+def test_flash_attention_kernel(B, Sq, Sk, H, K, d, causal, dtype):
+    """The kernel's forward against the oracle."""
     q = jnp.asarray(RNG.normal(size=(B, Sq, H, d)), dtype)
     k = jnp.asarray(RNG.normal(size=(B, Sk, K, d)), dtype)
     v = jnp.asarray(RNG.normal(size=(B, Sk, K, d)), dtype)
-    o = ops.flash_attention(q, k, v, causal=causal, window=window,
-                            tq=64, tk=64, interpret=True)
+    o = ops.flash_attention(q, k, v, causal=causal, interpret=True)
     kr, vr = jnp.repeat(k, H // K, axis=2), jnp.repeat(v, H // K, axis=2)
-    o_ref = ref.flash_attention_ref(q, kr, vr, causal=causal, window=window)
+    o_ref = ref.flash_attention_ref(q, kr, vr, causal=causal)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(o_ref, np.float32), atol=tol * 50)
+
+
+@pytest.mark.parametrize("B,Sq,Sk,H,K,d,causal", [
+    (2, 100, 100, 2, 2, 64, False),       # pads queries and keys
+    (2, 48, 200, 2, 2, 64, False),        # cross-attention's shape
+    (1, 128, 128, 2, 2, 64, True),
+    (1, 128, 128, 4, 2, 32, True),        # GQA
+    (1, 2048, 2048, 2, 2, 64, True),      # 4 q x 2 k blocks, one skipped
+    (1, 600, 1600, 2, 1, 64, False),      # 5 q x 13 k blocks, both padded
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_grads(B, Sq, Sk, H, K, d, causal, dtype):
+    """The kernel's forward and its dq, dk, dv (the Pallas backward
+    kernels, in TPU interpret mode) against autodiff of the jnp core, on
+    one block and on several blocks of both axes (`flash.block_sizes`)."""
+    from repro.models.attention import blockwise_attention
+    q = jnp.asarray(RNG.normal(size=(B, Sq, H, d)), dtype)
+    k = jnp.asarray(RNG.normal(size=(B, Sk, K, d)), dtype)
+    v = jnp.asarray(RNG.normal(size=(B, Sk, K, d)), dtype)
+    w = jnp.asarray(RNG.normal(size=(B, Sq, H, d)), jnp.float32)
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)
+                                       * w)
+
+    kernel = lambda q, k, v: ops.flash_attention(q, k, v, causal=causal,
+                                                 interpret=True)
+    oracle = lambda q, k, v: blockwise_attention(q, k, v, causal=causal,
+                                                 chunk_k=64)
+    got = (kernel(q, k, v),) + jax.grad(loss(kernel), (0, 1, 2))(q, k, v)
+    want = (oracle(q, k, v),) + jax.grad(loss(oracle), (0, 1, 2))(q, k, v)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype, name
+        b = np.asarray(b, np.float32)
+        np.testing.assert_allclose(np.asarray(a, np.float32), b,
+                                   atol=tol * np.abs(b).max(), err_msg=name)
 
 
 @settings(max_examples=10, deadline=None)
